@@ -1,2 +1,1 @@
-from .ops import (delta_apply, flash_attention, group_updates_by_page,
-                  ssd_scan, use_interpret, wkv6)
+from .ops import group_updates_by_page

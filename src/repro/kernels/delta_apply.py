@@ -7,10 +7,10 @@ pages are arrays of slots — so redo is a masked batched scatter:
 
     pages[page_idx[u], slot[u], :] = value[u]        where mask[u]
 
-The wrapper (ops.apply_deltas) groups updates by destination page (sort +
-pad to a per-page budget) so the kernel's grid walks pages: each page tile is
-resident in VMEM exactly once while all its updates stream through — the
-TPU-native analogue of "fetch the page once, apply every log record for it"
+The host packer (ops.group_updates_by_page) groups updates by destination
+page (sort + pad to a per-page budget) so the kernel's grid walks pages: each
+page tile is resident in VMEM exactly once while all its updates stream
+through — the TPU-native analogue of "fetch the page once, apply every log record for it"
 (the same IO-locality insight the paper's prefetch/DPT machinery serves).
 
 mode='assign' replays after-images (idempotent, any order within a page once
@@ -23,26 +23,27 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _delta_kernel(vals_ref, slot_ref, mask_ref, page_in_ref, page_out_ref, *,
                   max_upd: int, additive: bool):
-    page = page_in_ref[0]                         # (slots, width) f32
-    vals = vals_ref[0]                            # (max_upd, width)
-    slots = slot_ref[0]                           # (max_upd,) int32
-    mask = mask_ref[0]                            # (max_upd,) bool
+    # slot_ref / mask_ref: (1, 1, max_upd) int32 in SMEM — scalar reads
+    page_out_ref[...] = page_in_ref[...]          # (1, slots, width)
 
-    def body(u, pg):
-        slot = slots[u]
-        ok = mask[u]
-        cur = jax.lax.dynamic_slice_in_dim(pg, slot, 1, axis=0)
-        new = vals[u][None, :]
-        if additive:
-            new = cur + new
-        new = jnp.where(ok, new, cur)
-        return jax.lax.dynamic_update_slice_in_dim(pg, new, slot, axis=0)
+    def body(u, carry):
+        slot = slot_ref[0, 0, u]
 
-    page_out_ref[0] = jax.lax.fori_loop(0, max_upd, body, page)
+        @pl.when(mask_ref[0, 0, u] != 0)
+        def _apply():
+            new = vals_ref[0, pl.ds(u, 1), :]
+            if additive:
+                new = new + page_out_ref[0, pl.ds(slot, 1), :]
+            page_out_ref[0, pl.ds(slot, 1), :] = new
+
+        return carry
+
+    jax.lax.fori_loop(0, max_upd, body, 0)
 
 
 def delta_apply(pages, vals, slot_idx, mask, *, additive: bool = False,
@@ -59,11 +60,14 @@ def delta_apply(pages, vals, slot_idx, mask, *, additive: bool = False,
         grid=(n_pages,),
         in_specs=[
             pl.BlockSpec((1, max_upd, width), lambda p: (p, 0, 0)),
-            pl.BlockSpec((1, max_upd), lambda p: (p, 0)),
-            pl.BlockSpec((1, max_upd), lambda p: (p, 0)),
+            pl.BlockSpec((1, 1, max_upd), lambda p: (p, 0, 0),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, 1, max_upd), lambda p: (p, 0, 0),
+                         memory_space=pltpu.SMEM),
             pl.BlockSpec((1, slots, width), lambda p: (p, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, slots, width), lambda p: (p, 0, 0)),
         out_shape=jax.ShapeDtypeStruct(pages.shape, pages.dtype),
         interpret=interpret,
-    )(vals, slot_idx, mask, pages)
+    )(vals, slot_idx[:, None, :], mask.astype(jnp.int32)[:, None, :],
+      pages)

@@ -1,48 +1,12 @@
-"""jit'd public wrappers for the Pallas kernels.
+"""Host-side helpers for the Pallas kernels.
 
-On CPU (this container) kernels run in interpret mode — the kernel body
-executes as traced jax ops, validating logic against the oracles in ref.py.
-On TPU they compile to Mosaic.  ``use_interpret()`` picks automatically.
+The kernels take ``interpret=`` from their caller: True runs the kernel body
+as traced jax ops (the CPU tests check it against ref.py), False compiles it
+with Mosaic for the TPU.
 """
 from __future__ import annotations
 
-import functools
-
-import jax
-import jax.numpy as jnp
 import numpy as np
-
-from .delta_apply import delta_apply as _delta_apply
-from .flash_attention import flash_attention as _flash
-from .ssd_scan import ssd_scan as _ssd
-from .wkv6 import wkv6 as _wkv6
-
-
-def use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
-@functools.partial(jax.jit, static_argnames=("causal", "q_block", "kv_block"))
-def flash_attention(q, k, v, *, causal: bool = True, q_block: int = 128,
-                    kv_block: int = 128):
-    return _flash(q, k, v, causal=causal, q_block=q_block, kv_block=kv_block,
-                  interpret=use_interpret())
-
-
-@functools.partial(jax.jit, static_argnames=("chunk",))
-def wkv6(r, k, v, logw, u, *, chunk: int = 64):
-    return _wkv6(r, k, v, logw, u, chunk=chunk, interpret=use_interpret())
-
-
-@functools.partial(jax.jit, static_argnames=("chunk",))
-def ssd_scan(x, dt, B_in, C_in, A, *, chunk: int = 128):
-    return _ssd(x, dt, B_in, C_in, A, chunk=chunk, interpret=use_interpret())
-
-
-@functools.partial(jax.jit, static_argnames=("additive",))
-def delta_apply(pages, vals, slot_idx, mask, *, additive: bool = False):
-    return _delta_apply(pages, vals, slot_idx, mask, additive=additive,
-                        interpret=use_interpret())
 
 
 def group_updates_by_page(page_idx: np.ndarray, n_pages: int,

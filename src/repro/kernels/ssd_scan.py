@@ -36,35 +36,44 @@ def _ssd_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, y_ref, h_ref, *,
         h_ref[...] = jnp.zeros_like(h_ref)
 
     x = x_ref[0, 0].astype(jnp.float32)          # (C, P)
-    dt = dt_ref[0, 0].astype(jnp.float32)        # (C,)
+    dt = dt_ref[0, 0].astype(jnp.float32)        # (1, C) row
     Bm = b_ref[0].astype(jnp.float32)            # (C, N)
     Cm = c_ref[0].astype(jnp.float32)            # (C, N)
-    A = a_ref[0].astype(jnp.float32)             # scalar (per head)
+    A = a_ref[pl.program_id(1)]                  # f32 scalar (per head), SMEM
     h = h_ref[...]                                # (P, N)
 
-    cd = jnp.cumsum(dt)                           # (C,)
+    # row t of the (C, C) masks is step t; column s is step s.  The prefix
+    # sums are masked lane reductions (Mosaic has no cumsum)
+    row = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    dts = jnp.broadcast_to(dt, (chunk, chunk))    # [t, s] = dt_s
+    cd = jnp.sum(jnp.where(col <= row, dts, 0.0), axis=1,
+                 keepdims=True)                   # (C, 1) cumsum dt
+    dt_col = jnp.sum(jnp.where(col == row, dts, 0.0), axis=1,
+                     keepdims=True)               # (C, 1)
+    total = jnp.sum(dt)                           # cd at the chunk's end
     decay = jnp.exp(A * cd)                       # L_t
 
     # inter-chunk: y[t] = C_t . (L_t * h)  -> (C,P)
-    y_inter = decay[:, None] * jax.lax.dot_general(
+    y_inter = decay * jax.lax.dot_general(
         Cm, h, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
 
-    # intra-chunk
+    # intra-chunk: dt_s folds into x_s
     scores = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)  # (C,C)
-    pair = jnp.exp(A * (cd[:, None] - cd[None, :]))
-    tri = (jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-           >= jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1))
-    M = jnp.where(tri, scores * pair, 0.0) * dt[None, :]
-    y_intra = jax.lax.dot_general(M, x, (((1,), (0,)), ((), ())),
+    cds = jnp.broadcast_to(cd, (chunk, chunk))    # [t, s] = cd_t
+    pair = jnp.exp(A * (cds - cds.T))
+    M = jnp.where(col <= row, scores * pair, 0.0)
+    xdt = x * dt_col
+    y_intra = jax.lax.dot_general(M, xdt, (((1,), (0,)), ((), ())),
                                   preferred_element_type=jnp.float32)
 
     y_ref[0, 0] = (y_inter + y_intra).astype(y_ref.dtype)
 
     # carry
-    w = jnp.exp(A * (cd[-1] - cd)) * dt           # (C,)
-    h_new = (jnp.exp(A * cd[-1]) * h
-             + jax.lax.dot_general(x * w[:, None], Bm,
+    w = jnp.exp(A * (total - cd))                 # (C, 1)
+    h_new = (jnp.exp(A * total) * h
+             + jax.lax.dot_general(xdt * w, Bm,
                                    (((0,), (0,)), ((), ())),
                                    preferred_element_type=jnp.float32))
     h_ref[...] = h_new
@@ -85,13 +94,13 @@ def ssd_scan(x, dt, B_in, C_in, A, *, chunk: int = DEFAULT_CHUNK,
         grid=(Bsz, H, nt),
         in_specs=[
             pl.BlockSpec((1, 1, chunk, P), lambda b, h, t: (b, h, t, 0)),
-            pl.BlockSpec((1, 1, chunk), lambda b, h, t: (b, h, t)),
+            pl.BlockSpec((1, 1, 1, chunk), lambda b, h, t: (b, h, 0, t)),
             pl.BlockSpec((1, chunk, N), lambda b, h, t: (b, t, 0)),
             pl.BlockSpec((1, chunk, N), lambda b, h, t: (b, t, 0)),
-            pl.BlockSpec((1,), lambda b, h, t: (h,)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((1, 1, chunk, P), lambda b, h, t: (b, h, t, 0)),
         out_shape=jax.ShapeDtypeStruct((Bsz, H, T, P), x.dtype),
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
         interpret=interpret,
-    )(x, dt, B_in, C_in, A)
+    )(x, dt[:, :, None, :], B_in, C_in, A.astype(jnp.float32))

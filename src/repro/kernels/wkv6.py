@@ -40,36 +40,48 @@ def _wkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, y_ref, s_ref, *,
     k = k_ref[0, 0].astype(jnp.float32)
     v = v_ref[0, 0].astype(jnp.float32)
     lw = lw_ref[0, 0].astype(jnp.float32)        # log decay, negative
-    u = u_ref[0].astype(jnp.float32)             # (hd,)
+    u = u_ref[0].astype(jnp.float32)             # (1, hd)
     S = s_ref[...]                                # (hd_k, hd_v)
+    hd = S.shape[0]
 
-    c = jnp.cumsum(lw, axis=0)                   # (C, hd)
+    def mm(a, b):
+        return jax.lax.dot_general(a, b, (((1,), (0,)), ((), ())),
+                                   precision=jax.lax.Precision.HIGHEST,
+                                   preferred_element_type=jnp.float32)
+
+    row = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    # prefix sum over steps as a lower-triangular matmul (Mosaic has no
+    # cumsum)
+    c = mm(jnp.where(col <= row, 1.0, 0.0), lw)  # (C, hd)
     c_prev = c - lw                              # c_{t-1}
 
     # inter-chunk: y_inter[t] = (r_t * exp(c_{t-1})) @ S
-    r_decayed = r * jnp.exp(c_prev)
-    y_inter = jax.lax.dot_general(r_decayed, S, (((1,), (0,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
+    y_inter = mm(r * jnp.exp(c_prev), S)
 
-    # intra-chunk pairwise: A[t,s] = sum_i r_t k_s exp(c_{t-1} - c_s), s<t
+    # intra-chunk pairwise: A[t,s] = sum_i r_t k_s exp(c_{t-1} - c_s), s<t.
+    # Log decays are negative, so diff <= 0 wherever s < t; the clamp only
+    # keeps the masked-out s >= t entries finite
     diff = c_prev[:, None, :] - c[None, :, :]    # (C, C, hd)
-    tri = (jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-           > jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1))
-    pair = jnp.where(tri[:, :, None], jnp.exp(diff), 0.0)
-    A = jnp.einsum("ti,si,tsi->ts", r, k, pair)
-    A_diag = jnp.sum(r * k * u[None, :], axis=1)  # bonus on the diagonal
-    A = A + jnp.diag(A_diag)
-    y_intra = jax.lax.dot_general(A, v, (((1,), (0,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
+    pair = jnp.exp(jnp.minimum(diff, 0.0))
+    A = jnp.sum(r[:, None, :] * k[None, :, :] * pair, axis=2)
+    A = jnp.where(col < row, A, 0.0)
+    # the diagonal carries the u bonus instead of the decay
+    A_diag = jnp.sum(r * k * u, axis=1, keepdims=True)   # (C, 1)
+    A = A + jnp.where(col == row, A_diag, 0.0)
+    y_intra = mm(A, v)
 
     y_ref[0, 0] = (y_inter + y_intra).astype(y_ref.dtype)
 
     # state update: S' = diag(e^{c_C}) S + (k * e^{c_C - c})^T @ v
-    c_total = c[-1]                               # (hd,)
-    k_decayed = k * jnp.exp(c_total[None, :] - c)
-    s_ref[...] = (jnp.exp(c_total)[:, None] * S
+    c_total = c[chunk - 1:chunk, :]               # (1, hd)
+    k_decayed = k * jnp.exp(c_total - c)
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (hd, hd), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (hd, hd), 1))
+    s_ref[...] = (mm(jnp.where(eye, jnp.exp(c_total), 0.0), S)
                   + jax.lax.dot_general(k_decayed, v,
                                         (((0,), (0,)), ((), ())),
+                                        precision=jax.lax.Precision.HIGHEST,
                                         preferred_element_type=jnp.float32))
 
 
@@ -87,9 +99,9 @@ def wkv6(r, k, v, logw, u, *, chunk: int = DEFAULT_CHUNK,
         grid=(B, H, nt),
         in_specs=[tile, tile, tile,
                   tile,
-                  pl.BlockSpec((1, hd), lambda b, h, t: (h, 0))],
+                  pl.BlockSpec((1, 1, hd), lambda b, h, t: (h, 0, 0))],
         out_specs=tile,
         out_shape=jax.ShapeDtypeStruct((B, H, T, hd), r.dtype),
         scratch_shapes=[pltpu.VMEM((hd, hd), jnp.float32)],
         interpret=interpret,
-    )(r, k, v, logw, u)
+    )(r, k, v, logw, u[:, None, :])

@@ -61,7 +61,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, out_dir: Path,
     try:
         mesh = make_production_mesh(multi_pod=(mesh_kind == "multi"))
         spec = make_spec(cfg, shape, mesh)
-        with mesh:
+        with jax.set_mesh(mesh):
             lowered = jax.jit(spec.fn).lower(*spec.args)
             t_lower = time.time() - t0
             compiled = lowered.compile()

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.train import preset_config
 from repro.models import build_model, make_batch
 
@@ -29,6 +30,7 @@ def main() -> None:
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--gen", type=int, default=16)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = preset_config(get_config(args.arch), args.preset)
     api = build_model(cfg)

@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.parallel.sharding import LAYOUT
 
 Array = jax.Array
 BIG_NEG = -2.0 ** 30
@@ -31,47 +32,30 @@ def shard_hint(x: Array, *axes) -> Array:
     """with_sharding_constraint against the ambient mesh, if any.
 
     ``axes`` entries: 'batch' (expands to whichever of pod/data exist),
-    'model', 'data', or None.  Outside a mesh context (unit tests, smoke
-    tests) this is the identity, so model code can hint unconditionally.
+    'model', 'data', or None.  Outside a mesh context (``jax.set_mesh``;
+    unit tests, one-chip runs) this is the identity, so model code can hint
+    unconditionally.
     """
-    names: set = set()
-    try:                                   # classic `with mesh:` context
-        from jax._src import mesh as _mesh_lib
-        m = _mesh_lib.thread_resources.env.physical_mesh
-        if m is not None and not m.empty:
-            names = set(m.axis_names)
-    except (ImportError, AttributeError):
-        pass
-    if not names:
-        try:                               # new explicit-sharding context
-            m = jax.sharding.get_abstract_mesh()
-            if m is not None and m.axis_names:
-                names = set(m.axis_names)
-        except (ImportError, AttributeError):
-            pass
-    if not names:
+    m = jax.sharding.get_abstract_mesh()
+    if m.empty:
         return x
-    try:
-        from repro.parallel.sharding import LAYOUT
-        layout = LAYOUT.get()
-    except (ImportError, AttributeError, LookupError):
-        layout = "tp"
+    names = set(m.axis_names)
+    mesh_sizes = dict(m.shape)
+    layout = LAYOUT.get()
     fsdp = layout in ("fsdp", "ep")    # no TP on feature dims
     batch_gets_model = layout == "fsdp"
-    mesh_sizes = dict(zip(m.axis_names, m.devices.shape)) \
-        if hasattr(m, "devices") else {}
     spec = []
     for i, a in enumerate(axes):
         if a == "batch":
             cand = ("pod", "data", "model") if batch_gets_model \
                 else ("pod", "data")
             ba = tuple(n for n in cand if n in names)
-            if ba and mesh_sizes and i < x.ndim:
+            if ba and i < x.ndim:
                 total = 1
                 for n in ba:
-                    total *= mesh_sizes.get(n, 1)
+                    total *= mesh_sizes[n]
                 while ba and x.shape[i] % total != 0:
-                    total //= mesh_sizes.get(ba[-1], 1)
+                    total //= mesh_sizes[ba[-1]]
                     ba = ba[:-1]
             spec.append(ba if ba else None)
         elif a == "expert":
@@ -83,12 +67,8 @@ def shard_hint(x: Array, *axes) -> Array:
             spec.append(None if (fsdp and a == "model") else a)
         else:
             spec.append(None)
-    try:
-        return jax.lax.with_sharding_constraint(
-            x, jax.sharding.PartitionSpec(*spec))
-    # reprolint: allow(loud-corruption) — sharding hints are best-effort: outside a mesh context the constraint is meaningless and the identity is the correct degradation
-    except Exception:
-        return x
+    return jax.lax.with_sharding_constraint(
+        x, jax.sharding.PartitionSpec(*spec))
 
 
 # ----------------------------------------------------------------- norms

@@ -22,7 +22,9 @@ SCRIPT = textwrap.dedent("""
     from repro.parallel.sharding import param_pspec, set_layout
     from repro.models import build_model
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from jax.sharding import AxisType
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     out = {}
 
     # --- param rules (full config shapes, no allocation)
@@ -42,13 +44,13 @@ SCRIPT = textwrap.dedent("""
         get_config("llama3.2-3b").reduced(), n_kv_heads=4)
     shape = dataclasses.replace(TRAIN_4K, seq_len=64, global_batch=8)
     spec = make_spec(red, shape, mesh)
-    with mesh:
+    with jax.set_mesh(mesh):
         compiled = jax.jit(spec.fn).lower(*spec.args).compile()
     out["train_compiles"] = True
 
     shape_d = dataclasses.replace(DECODE_32K, seq_len=128, global_batch=8)
     spec = make_spec(red, shape_d, mesh)
-    with mesh:
+    with jax.set_mesh(mesh):
         compiled = jax.jit(spec.fn).lower(*spec.args).compile()
     out["decode_compiles"] = True
 

@@ -1,7 +1,25 @@
 """End-to-end driver smoke: launch.train with crash+restore, in-process."""
+import importlib.util
 import sys
+from pathlib import Path
 
+import jax
 import pytest
+from jax.experimental.compilation_cache import compilation_cache
+
+from repro.launch.compile_cache import use_compile_cache
+
+ROOT = Path(__file__).resolve().parents[1]
+
+
+@pytest.fixture(autouse=True)
+def _restore_compile_cache():
+    """The drivers turn the persistent compile cache on; the rest of this
+    process keeps the setting it had."""
+    was = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", was)
+    compilation_cache.reset_cache()
 
 
 def test_train_driver_crash_restore(capsys, monkeypatch):
@@ -27,3 +45,28 @@ def test_serve_driver(capsys, monkeypatch):
     out = capsys.readouterr().out
     assert "prefill: batch=2" in out
     assert "decode: 3 steps" in out
+
+
+def test_compile_cache_defaults_to_checkout(monkeypatch):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    path = use_compile_cache()
+    assert path == str(ROOT / ".jax_cache")
+    assert jax.config.jax_compilation_cache_dir == path
+
+
+def test_compile_cache_env_wins(monkeypatch, tmp_path):
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    before = jax.config.jax_compilation_cache_dir
+    assert use_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_chip_smoke_refuses_cpu(capsys):
+    """No fallback: without a TPU the smoke fails and reports no result."""
+    spec = importlib.util.spec_from_file_location("chip_smoke",
+                                                  ROOT / "chip_smoke.py")
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    assert jax.devices()[0].platform == "cpu"
+    assert mod.main() != 0
+    assert '"ok"' not in capsys.readouterr().out
