@@ -1,0 +1,58 @@
+"""Each mix's window run end to end at a tiny size on the CPU, through the
+same program calls a chip run makes (``require_chips`` is the only step
+left out)."""
+import math
+
+import pytest
+
+from chipbench import run as R
+
+
+@pytest.mark.parametrize("workload", ["tiny_whisper.save10",
+                                      "tiny_whisper.resume",
+                                      "tiny_whisper.nolog",
+                                      "tiny_qwen2.save10"])
+def test_mix_window_end_to_end(bench, cpu, workload):
+    out = R.run_cell(bench, workload, 2**33 + 11, 0.2, False, chips=cpu)
+    assert out["correct"], out["checks"]
+    assert out["failed"] == 0 and out["attempted"] > 0
+    assert list(out)[-1] == "checks"
+    names = set(out["metrics"])
+    assert "setup_s" in names
+    assert names & {"train_tokens_per_s", "resume_s"}
+    for m in out["metrics"].values():
+        assert math.isfinite(m["value"]) and m["value"] > 0
+    assert out["device"]["count"] == 1
+
+
+def test_periods_window_is_whole_periods(bench, cpu):
+    out = R.run_cell(bench, "tiny_whisper.save10", 5, 0.0, False, chips=cpu)
+    assert out["counts"]["steps"] == 20           # at least one period
+    assert out["counts"]["final_step"] % 10 == 0  # ends at a save
+
+
+def test_resume_window_runs_at_least_one_resume(bench, cpu):
+    out = R.run_cell(bench, "tiny_whisper.resume", 6, 0.0, False, chips=cpu)
+    assert out["counts"]["resumes"] == 1
+    assert out["checks"]["resumes_not_exact"]["value"] == 0
+
+
+def test_same_seed_same_inputs_and_weights():
+    import jax
+    import jax.numpy as jnp
+
+    from chipbench import traffic
+    shapes = {"embed": jax.ShapeDtypeStruct((16, 8), jnp.bfloat16),
+              "w": jax.ShapeDtypeStruct((8, 4), jnp.bfloat16)}
+    seed = 2**40 + 3                       # past 32 bits, as the driver's
+    a, b = traffic.make_params(seed, shapes), traffic.make_params(seed, shapes)
+    c = traffic.make_params(seed + 1, shapes)
+    assert all(jnp.array_equal(x, y) for x, y in
+               zip(jax.tree.leaves(a), jax.tree.leaves(b)))
+    assert not jnp.array_equal(a["w"], c["w"])
+    job = {"batch": 2, "seq": 8, "frames": 4}
+    f, g = (traffic.make_feed(seed, job, 100, 8) for _ in range(2))
+    assert jnp.array_equal(f(3)["tokens"], g(3)["tokens"])
+    assert not jnp.array_equal(f(3)["tokens"], f(4)["tokens"])
+    t = f(0)["tokens"]
+    assert len({tuple(r) for r in t.tolist()}) == 2       # rows differ
