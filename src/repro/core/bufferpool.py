@@ -20,6 +20,7 @@ from typing import Callable, Dict, Optional
 
 from ..obs import metrics as _metrics
 from ..obs.flightrec import FLIGHT as _FLIGHT
+from ..obs.trace import TRACER as _TRACER
 from .log import LogManager
 from .pages import Page
 from .records import LSN, NULL_LSN, PID
@@ -198,26 +199,28 @@ class BufferPool:
         the same page on consecutive rounds anyway.  Correctness is
         untouched — any flush schedule is WAL-legal, a skipped page just
         stays in the DPT one round longer."""
-        self._flush_tick += 1
-        tick = self._flush_tick
-        dirty = [(b.rlsn, pid) for pid, b in self.buffers.items()
-                 if b.dirty and b.bg_flush_tick < tick - 1]
-        dirty.sort()
-        n = 0
-        from ..media.errors import BackendUnavailableError
-        for _, pid in dirty[:max_pages]:
-            try:
-                flushed = self.flush_page(pid)
-            except BackendUnavailableError:
-                # background flushing is optional by construction (any
-                # flush schedule is WAL-legal): the page stays dirty and
-                # the next round retries it.  flush_page accounted the
-                # failure; outage-wide pressure shows up as a flush_failures
-                # ramp, not a dead pool.
-                continue
-            if flushed:
-                self.buffers[pid].bg_flush_tick = tick
-                n += 1
+        with _TRACER.span("pool.bg_flush") as sp:
+            self._flush_tick += 1
+            tick = self._flush_tick
+            dirty = [(b.rlsn, pid) for pid, b in self.buffers.items()
+                     if b.dirty and b.bg_flush_tick < tick - 1]
+            dirty.sort()
+            n = 0
+            from ..media.errors import BackendUnavailableError
+            for _, pid in dirty[:max_pages]:
+                try:
+                    flushed = self.flush_page(pid)
+                except BackendUnavailableError:
+                    # background flushing is optional by construction (any
+                    # flush schedule is WAL-legal): the page stays dirty
+                    # and the next round retries it.  flush_page accounted
+                    # the failure; outage-wide pressure shows up as a
+                    # flush_failures ramp, not a dead pool.
+                    continue
+                if flushed:
+                    self.buffers[pid].bg_flush_tick = tick
+                    n += 1
+            sp.set(scanned=len(self.buffers), flushed=n)
         return n
 
     # ------------------------------------------------------------- checkpoint

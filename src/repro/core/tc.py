@@ -15,6 +15,7 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Optional
 
+from ..obs import metrics as _metrics
 from ..obs.flightrec import FLIGHT as _FLIGHT
 from ..obs.flightrec import auto_dump as _flight_dump
 from .dc import DataComponent, make_key, rec_key, table_range
@@ -23,6 +24,9 @@ from .records import (LSN, NULL_LSN, AbortRec, BeginCkptRec, CLRRec,
                       CommitRec, EndCkptRec, RecKind, SnapshotRec, TxnId,
                       UpdateRec)
 from .storage import PageStore
+
+#: before- plus after-image bytes of every update record the TC logs
+_C_LOG_BYTES = _metrics.counter("log.bytes_appended")
 
 
 class TransactionalComponent:
@@ -51,6 +55,7 @@ class TransactionalComponent:
         rec = UpdateRec(txn=txn, table=table, key=key, before=before,
                         after=after, prev_lsn=self.active[txn], op=op)
         self.log.append(rec)
+        _C_LOG_BYTES.inc(len(before or b"") + len(after or b""))
         self.active[txn] = rec.lsn
         self._first_writes.setdefault(txn, {}).setdefault(
             (table, key), (rec.lsn, before))
@@ -176,6 +181,7 @@ class TransactionalComponent:
                             before=s.before, after=s.after,
                             prev_lsn=active[txn], op=s.op, ck=s.ck)
             log.append(rec)
+            _C_LOG_BYTES.inc(len(s.before or b"") + len(s.after or b""))
             active[txn] = rec.lsn
             self._first_writes.setdefault(txn, {}).setdefault(
                 (s.table, s.key), (rec.lsn, s.before))

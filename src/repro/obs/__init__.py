@@ -17,9 +17,14 @@ Quick tour::
 
 Metrics (counters/gauges/histograms) are always on — a probe costs one
 attribute increment, same as the ``self.x += 1`` counters it unifies.
-Tracing is off by default; every tracing probe no-ops behind a shared null
-span / an ``if TRACER.enabled`` guard, and the bound is CI-asserted (see
-``benchmarks/recovery_bench.bench_probe_overhead``).
+Tracing is off by default.  A span is live while ``obs.enable()`` is in
+force or a JAX profiler session is capturing; a live span records into
+``TRACER.events`` and, under the profiler, also opens a ``TraceMe`` on the
+profiler's clock (``obs.trace``).  Otherwise every span is a shared null
+span and every point event sits behind an ``if TRACER.enabled`` guard.
+``benchmarks/recovery_bench.bench_probe_overhead`` bounds that cost; it is
+a CPU benchmark, not part of the tier-1 tests.  Importing ``obs`` never
+imports ``jax``.
 
 The flight recorder (``obs.flightrec``) is the third tier: always on like
 metrics, event-shaped like the tracer, bounded like neither needs to be —

@@ -1,10 +1,29 @@
-"""Structured span/event tracer with a no-op fast path.
+"""Structured span/event tracer with a no-op fast path, and a bridge to the
+JAX profiler's clock.
 
-Tracing is **off by default** and every probe in the hot paths is written
-as either ``with TRACER.span(...)`` (which returns a shared no-op span when
-disabled) or ``if TRACER.enabled: TRACER.event(...)`` (so the kwargs dict
-is never even built).  The CI-asserted bound in
-``benchmarks/recovery_bench.bench_probe_overhead`` keeps this honest.
+A span is *live* when ``TRACER.enabled`` is set or a JAX profiler session
+is capturing (``TraceMe.is_enabled()``).  A live span
+
+- records its begin/end events in ``TRACER.events`` (below);
+- under a capturing profiler, also opens a ``TraceMe`` with its name and
+  attributes, so it lands on the trace's ``/host:CPU`` plane on the same
+  clock as the device's events; ``span.set(...)`` is forwarded to
+  ``set_metadata`` and appears as that event's stats.
+
+A span that is not live is the shared null span: it records nothing.
+Point events (``Tracer.event``) stay in memory, and only while
+``TRACER.enabled`` is set: they run per page read, so hot paths guard the
+*call* with ``if TRACER.enabled`` and the kwargs dict is never built.
+``benchmarks/recovery_bench.bench_probe_overhead`` bounds the cost of the
+probes when off; it is a CPU benchmark, not part of the tier-1 tests.
+
+The store is pure Python: this module never imports ``jax``.  It looks
+for ``TraceMe`` only once ``jax`` is in ``sys.modules`` — a process that
+never imported JAX cannot be under its profiler.
+
+Python's garbage collections are spans too (``gc``, with ``generation``
+and ``collected``), opened and closed by a ``gc.callbacks`` hook while
+spans are live.
 
 Event model — a flat list of dicts, one per line in the JSONL export:
 
@@ -19,19 +38,46 @@ Event model — a flat list of dicts, one per line in the JSONL export:
 construction or last ``clear()``); ``wall`` on begin events anchors the
 trace to wall-clock time.  Span ids are per-tracer-epoch; ``parent`` is the
 innermost open span at emit time (0 = root).  Attributes set *during* a
-span (``span.set(...)``) appear on its end event.
+span (``span.set(...)``) appear on its end event.  Events accumulate for as
+long as spans are live; ``clear()`` drops them.
 
-``TRACER`` is a process-wide singleton; toggle ``TRACER.enabled`` (or the
-``enable()``/``disable()`` shims) — never rebind the name, call sites
+``TRACER`` is a process-wide singleton; toggle ``TRACER.enabled`` (or
+``obs.enable()``/``obs.disable()``) — never rebind the name, call sites
 capture the object at import.
 """
 from __future__ import annotations
 
+import gc
 import json
+import sys
 import time
 from pathlib import Path
 from types import TracebackType
 from typing import Any, Dict, List, Optional, Type, Union
+
+#: ``jax.profiler.TraceAnnotation`` (a ``TraceMe``) once ``jax`` is loaded
+_TRACEME: Any = None
+
+
+def _traceme() -> Any:
+    """The profiler's ``TraceMe`` type, or None while ``jax`` is not
+    imported (this module never imports it)."""
+    global _TRACEME
+    if _TRACEME is None:
+        jax = sys.modules.get("jax")
+        profiler = getattr(jax, "profiler", None)
+        if profiler is not None:
+            _TRACEME = profiler.TraceAnnotation
+    return _TRACEME
+
+
+def _stats(attrs: Dict[str, Any]) -> Dict[str, Any]:
+    """``attrs`` as ``TraceMe`` takes them for event stats."""
+    for v in attrs.values():
+        if not isinstance(v, (int, float, str)):
+            return {k: v if isinstance(v, (int, float, str)) else str(v)
+                    for k, v in attrs.items()}
+    return attrs
 
 
 class _NullSpan:
@@ -54,20 +100,23 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("_tr", "name", "attrs", "span_id", "_t0")
+    __slots__ = ("_tr", "name", "attrs", "span_id", "_t0", "_me")
 
     def __init__(self, tracer: "Tracer", name: str,
-                 attrs: Dict[str, Any]):
+                 attrs: Dict[str, Any], traceme: Any = None):
         self._tr = tracer
         self.name = name
         self.attrs = attrs
         self.span_id = 0
         self._t0 = 0.0
+        self._me = None if traceme is None else traceme(name, **_stats(attrs))
 
     def __enter__(self) -> "_Span":
         tr = self._tr
         self.span_id = tr._next_id
         tr._next_id += 1
+        if self._me is not None:
+            self._me.__enter__()
         self._t0 = tr.now_ms()
         tr.events.append({
             "type": "begin", "span": self.span_id,
@@ -78,8 +127,11 @@ class _Span:
         return self
 
     def set(self, **attrs: object) -> "_Span":
-        """Attach/refresh attributes; they ride on the end event."""
+        """Attach/refresh attributes; they ride on the end event (and on
+        the profiler's event, as its stats)."""
         self.attrs.update(attrs)
+        if self._me is not None:
+            self._me.set_metadata(**_stats(attrs))
         return self
 
     def __exit__(self, exc_type: Optional[Type[BaseException]],
@@ -87,6 +139,8 @@ class _Span:
                  tb: Optional[TracebackType]) -> bool:
         tr = self._tr
         t1 = tr.now_ms()
+        if self._me is not None:
+            self._me.__exit__(exc_type, exc, tb)
         if tr._stack and tr._stack[-1] == self.span_id:
             tr._stack.pop()
         ev: Dict[str, Any] = {
@@ -113,10 +167,13 @@ class Tracer:
     # ------------------------------------------------------------- emission
     def span(self, name: str,
              **attrs: object) -> Union[_Span, _NullSpan]:
-        """Context manager for a nested span; no-op when disabled."""
-        if not self.enabled:
+        """Context manager for a nested span; the null span unless live
+        (enabled, or a profiler session capturing)."""
+        tm = _traceme()
+        capturing = tm is not None and tm.is_enabled()
+        if not (self.enabled or capturing):
             return _NULL_SPAN
-        return _Span(self, name, attrs)
+        return _Span(self, name, attrs, tm if capturing else None)
 
     def event(self, name: str, **attrs: object) -> None:
         """Point event, parented to the innermost open span.  Hot paths
@@ -160,17 +217,26 @@ def event(name: str, **attrs: object) -> None:
     TRACER.event(name, **attrs)
 
 
-def enable() -> None:
-    TRACER.enabled = True
-
-
-def disable() -> None:
-    TRACER.enabled = False
-
-
-def clear() -> None:
-    TRACER.clear()
-
-
 def export_jsonl(path: Union[str, Path]) -> Path:
     return TRACER.export_jsonl(path)
+
+
+# ------------------------------------------------------ garbage collection
+_gc_span: Optional[_Span] = None
+
+
+def _on_gc(phase: str, info: Dict[str, int]) -> None:
+    """``gc.callbacks`` hook: one ``gc`` span per collection while spans
+    are live; one check per collection otherwise."""
+    global _gc_span
+    if phase == "start":
+        sp = TRACER.span("gc", generation=info["generation"])
+        if isinstance(sp, _Span):
+            _gc_span = sp.__enter__()
+    elif _gc_span is not None:
+        sp, _gc_span = _gc_span, None
+        sp.set(collected=info["collected"])
+        sp.__exit__(None, None, None)
+
+
+gc.callbacks.append(_on_gc)

@@ -15,6 +15,8 @@ from typing import Any, Iterator
 import jax
 import numpy as np
 
+from repro.obs.trace import TRACER
+
 CHUNK_ELEMS = 16_384          # elements per record (~64 KiB fp32)
 _HDR = struct.Struct("<II")   # dtype code, n elements
 _DTYPES = ["float32", "bfloat16", "float16", "int32", "int64", "uint32",
@@ -45,41 +47,56 @@ def decode_chunk(raw: bytes) -> tuple[np.ndarray, str]:
 
 def tree_to_records(tree: Any, chunk_elems: int = CHUNK_ELEMS
                     ) -> Iterator[tuple[bytes, bytes]]:
-    """Yield (key, value) records for every chunk of every leaf."""
+    """Yield (key, value) records for every chunk of every leaf.
+
+    Spans per leaf (``TrainWAL.log_state`` saves through here):
+    ``wal.save.pull``, the copy to the host, then ``wal.save.records``,
+    its chunks.  The latter stays open while the caller consumes them, so
+    it times the caller's work on each chunk too."""
     for name, leaf in _leaf_paths(tree):
-        arr = np.asarray(leaf)
+        with TRACER.span("wal.save.pull"):
+            arr = np.asarray(leaf)
         dtype = str(leaf.dtype)
         view = (arr.view(np.uint16) if dtype == "bfloat16" else arr).reshape(-1)
         n = view.size
         n_chunks = max(1, (n + chunk_elems - 1) // chunk_elems)
-        for c in range(n_chunks):
-            part = view[c * chunk_elems:(c + 1) * chunk_elems]
-            key = f"{name}#{c:06d}".encode()
-            yield key, encode_chunk(part.tobytes(), dtype, part.size)
+        with TRACER.span("wal.save.records"):
+            for c in range(n_chunks):
+                part = view[c * chunk_elems:(c + 1) * chunk_elems]
+                key = f"{name}#{c:06d}".encode()
+                yield key, encode_chunk(part.tobytes(), dtype, part.size)
 
 
 def records_to_tree(template: Any, records: dict[bytes, bytes],
                     chunk_elems: int = CHUNK_ELEMS) -> Any:
-    """Rebuild a pytree shaped like ``template`` from chunk records."""
+    """Rebuild a pytree shaped like ``template`` from chunk records.
+
+    Spans per leaf (``TrainWAL.restore`` rebuilds through here):
+    ``wal.restore.decode``, its chunks decoded and joined on the host,
+    then ``wal.restore.put``, the leaf put on the device; one leaf at a
+    time is held decoded."""
     leaves = []
     for name, leaf in _leaf_paths(template):
         shape = leaf.shape
         dtype = str(leaf.dtype)
         n = int(np.prod(shape)) if shape else 1
         n_chunks = max(1, (n + chunk_elems - 1) // chunk_elems)
-        parts = []
-        for c in range(n_chunks):
-            key = f"{name}#{c:06d}".encode()
-            raw = records.get(key)
-            if raw is None:
-                raise KeyError(f"missing state chunk {key!r}")
-            arr, _ = decode_chunk(raw)
-            parts.append(arr)
-        flat = np.concatenate(parts) if len(parts) > 1 else parts[0]
-        if dtype == "bfloat16":
-            out = jax.numpy.asarray(flat.view(jax.numpy.bfloat16)).reshape(shape)
-        else:
-            out = jax.numpy.asarray(flat.reshape(shape))
+        with TRACER.span("wal.restore.decode"):
+            parts = []
+            for c in range(n_chunks):
+                key = f"{name}#{c:06d}".encode()
+                raw = records.get(key)
+                if raw is None:
+                    raise KeyError(f"missing state chunk {key!r}")
+                arr, _ = decode_chunk(raw)
+                parts.append(arr)
+            flat = np.concatenate(parts) if len(parts) > 1 else parts[0]
+        with TRACER.span("wal.restore.put"):
+            if dtype == "bfloat16":
+                out = jax.numpy.asarray(flat.view(jax.numpy.bfloat16)
+                                        ).reshape(shape)
+            else:
+                out = jax.numpy.asarray(flat.reshape(shape))
         leaves.append(out)
     treedef = jax.tree_util.tree_structure(template)
     return jax.tree_util.tree_unflatten(treedef, leaves)

@@ -30,12 +30,15 @@ import numpy as np
 
 from repro.core import Database, Strategy, recover
 from repro.core.dc import make_key
+from repro.obs import metrics as obs_metrics
+from repro.obs.trace import TRACER
 
 from .chunking import CHUNK_ELEMS, records_to_tree, tree_to_records
 
 META_TABLE = "meta"
 STATE_TABLE = "state"
 _META = struct.Struct("<qqq")      # step, cursor, state_step
+_C_LOG_BYTES = obs_metrics.counter("log.bytes_appended")
 
 
 @dataclass
@@ -70,44 +73,59 @@ class TrainWAL:
         ``delta_only`` skips chunks whose bytes did not change since the last
         log_state (embedding rows / routed experts / frozen towers) — the
         update stream becomes sparse, which is exactly the locality the
-        paper's DPT machinery exploits.  Commit forces the WAL."""
+        paper's DPT machinery exploits.  Commit forces the WAL.
+
+        Spans (live only under ``obs.enable()`` or a profiler session):
+        ``wal.log_state`` over ``wal.save.wait``, the per-leaf spans of
+        ``tree_to_records``, ``wal.save.commit`` and ``pool.bg_flush``."""
         import zlib
-        txn = self.db.tc.begin()
-        n_upd = 0
-        for key, value in tree_to_records(state, self.cfg.chunk_elems):
-            if delta_only and self._bootstrapped:
-                dig = zlib.crc32(value)
-                if self._digests.get(key) == dig:
-                    continue
-                self._digests[key] = dig
-            elif delta_only:
-                self._digests[key] = zlib.crc32(value)
-            if self._bootstrapped:
-                self.db.tc.update(txn, STATE_TABLE, key, value)
-            else:
-                self.db.tc.insert(txn, STATE_TABLE, key, value)
-            n_upd += 1
-            if n_upd % self.cfg.tracker_interval == 0:
+        with TRACER.span("wal.log_state", step=step) as sp:
+            with TRACER.span("wal.save.wait"):
+                jax.block_until_ready(state)
+            log0 = _C_LOG_BYTES.value
+            txn = self.db.tc.begin()
+            n_upd = n_skip = state_bytes = 0
+            for key, value in tree_to_records(state, self.cfg.chunk_elems):
+                state_bytes += len(value)
+                if delta_only and self._bootstrapped:
+                    dig = zlib.crc32(value)
+                    if self._digests.get(key) == dig:
+                        n_skip += 1
+                        continue
+                    self._digests[key] = dig
+                elif delta_only:
+                    self._digests[key] = zlib.crc32(value)
+                if self._bootstrapped:
+                    self.db.tc.update(txn, STATE_TABLE, key, value)
+                else:
+                    self.db.tc.insert(txn, STATE_TABLE, key, value)
+                n_upd += 1
+                if n_upd % self.cfg.tracker_interval == 0:
+                    self.db.dc.emit_trackers()
+            with TRACER.span("wal.save.commit"):
+                meta = _META.pack(step, cursor, step)
+                if self._bootstrapped:
+                    self.db.tc.update(txn, META_TABLE, b"latest", meta)
+                else:
+                    self.db.tc.insert(txn, META_TABLE, b"latest", meta)
+                self.db.tc.commit(txn)
+                self._bootstrapped = True
                 self.db.dc.emit_trackers()
-        meta = _META.pack(step, cursor, step)
-        if self._bootstrapped:
-            self.db.tc.update(txn, META_TABLE, b"latest", meta)
-        else:
-            self.db.tc.insert(txn, META_TABLE, b"latest", meta)
-        self.db.tc.commit(txn)
-        self._bootstrapped = True
-        self.db.dc.emit_trackers()
-        # keep tracker records themselves durable (group-committed)
-        self.db.log.flush()
-        self.db.dc.maybe_background_flush(self.cfg.bg_flush_pages)
+                # keep tracker records themselves durable (group-committed)
+                self.db.log.flush()
+            self.db.dc.maybe_background_flush(self.cfg.bg_flush_pages)
+            sp.set(chunks_written=n_upd, chunks_skipped=n_skip,
+                   log_bytes=_C_LOG_BYTES.value - log0,
+                   state_bytes=state_bytes)
 
     def log_step_meta(self, step: int, cursor: int, state_step: int) -> None:
         """Per-step heartbeat: step id + data cursor (tiny txn)."""
-        txn = self.db.tc.begin()
-        meta = _META.pack(step, cursor, state_step)
-        self.db.tc.update(txn, META_TABLE, b"latest", meta)
-        self.db.tc.commit(txn)
-        self.db.dc.maybe_background_flush(self.cfg.bg_flush_pages)
+        with TRACER.span("wal.log_step_meta", step=step):
+            txn = self.db.tc.begin()
+            meta = _META.pack(step, cursor, state_step)
+            self.db.tc.update(txn, META_TABLE, b"latest", meta)
+            self.db.tc.commit(txn)
+            self.db.dc.maybe_background_flush(self.cfg.bg_flush_pages)
 
     def maybe_checkpoint(self, step: int) -> bool:
         if step % self.cfg.ckpt_interval == 0 and step > 0:
@@ -123,21 +141,27 @@ class TrainWAL:
     def restore(cls, image, template_state: Any, wal_cfg: WALConfig | None = None,
                 strategy: Optional[Strategy] = None):
         """Recover the record store, rebuild the state pytree, return
-        (wal, state, step, cursor, state_step, recovery_stats)."""
-        cfg = wal_cfg or WALConfig()
-        db, stats = recover(image, strategy or cfg.strategy,
-                            cache_pages=cfg.cache_pages,
-                            page_size=cfg.page_size)
-        raw_meta = db.dc.read(META_TABLE, b"latest")
-        assert raw_meta is not None, "no committed training state to restore"
-        step, cursor, state_step = _META.unpack(raw_meta)
+        (wal, state, step, cursor, state_step, recovery_stats).
 
-        records: dict[bytes, bytes] = {}
-        prefix = make_key(STATE_TABLE, b"")
-        for k, v in db.scan_all():
-            if k.startswith(prefix):
-                records[k[len(prefix):]] = v
-        state = records_to_tree(template_state, records, cfg.chunk_elems)
+        Spans: ``wal.restore`` over ``recover``, ``wal.restore.scan`` and
+        the per-leaf spans of ``records_to_tree``."""
+        cfg = wal_cfg or WALConfig()
+        with TRACER.span("wal.restore"):
+            db, stats = recover(image, strategy or cfg.strategy,
+                                cache_pages=cfg.cache_pages,
+                                page_size=cfg.page_size)
+            raw_meta = db.dc.read(META_TABLE, b"latest")
+            assert raw_meta is not None, \
+                "no committed training state to restore"
+            step, cursor, state_step = _META.unpack(raw_meta)
+
+            with TRACER.span("wal.restore.scan"):
+                records: dict[bytes, bytes] = {}
+                prefix = make_key(STATE_TABLE, b"")
+                for k, v in db.scan_all():
+                    if k.startswith(prefix):
+                        records[k[len(prefix):]] = v
+            state = records_to_tree(template_state, records, cfg.chunk_elems)
 
         wal = cls.__new__(cls)
         wal.cfg = cfg

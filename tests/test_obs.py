@@ -185,11 +185,12 @@ def test_traced_batched_recovery_timeline_and_registry_view():
     assert recovered_state(db) == oracle
 
     ev = obs.TRACER.events
-    roots = obs.build_tree(ev)
+    # Python's collections are spans too, wherever they fall
+    roots = [r for r in obs.build_tree(ev) if r.name != "gc"]
     assert [r.name for r in roots] == ["recover"]
-    phases = [c.name for c in roots[0].children]
+    phases = [c.name for c in roots[0].children if c.name != "gc"]
     assert phases == ["analysis", "redo", "undo", "checkpoint"]
-    redo = roots[0].children[1]
+    redo, = [c for c in roots[0].children if c.name == "redo"]
     windows = [c for c in redo.children if c.name == "redo.window"]
     assert len(windows) == stats.windows >= 2
     assert sum(w.attrs["records"] for w in windows) == stats.log_records
