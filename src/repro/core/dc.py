@@ -216,6 +216,34 @@ class DataComponent:
         if self.delta is not None and rec.lsn > self.delta.applied_lsn:
             self.delta.applied_lsn = rec.lsn
 
+    def read_leaf(self, table: str, key: bytes
+                  ) -> tuple[bytes, PID, Optional[bytes]]:
+        """``read`` that also returns the composite key and the PID of the
+        leaf that owns it, so that ``put_in_leaf`` applies the update
+        without a second traversal."""
+        ck = make_key(table, key)
+        pid = self.btree.find_leaf(ck)
+        return ck, pid, self.pool.get(pid).get(ck)
+
+    def put_in_leaf(self, rec: UpdateRec, pid: PID) -> None:
+        """``apply`` for an update whose key ``read_leaf`` found in leaf
+        ``pid``, with no change to the tree since.  The page is fetched
+        again, so an eviction in between is harmless; a put that would
+        overflow the leaf splits through the ordinary ``btree.put``.
+        Stamps ``rec.pid`` as ``apply`` does."""
+        ck, after, lsn = rec.ck, rec.after, rec.lsn
+        page = self.pool.get(pid)
+        # a value no longer than the one it replaces always fits
+        if (rec.before is not None and len(after) <= len(rec.before)) \
+                or not page.would_overflow(ck, after, self.page_size):
+            page.put(ck, after, lsn)
+            self.pool.mark_dirty(pid, lsn)
+            rec.pid = pid
+        else:
+            rec.pid = self.btree.put(ck, after, lsn)
+        if self.delta is not None and lsn > self.delta.applied_lsn:
+            self.delta.applied_lsn = lsn
+
     def apply_clr(self, rec: CLRRec) -> None:
         k = rec_key(rec)
         if rec.op == RecKind.DELETE or rec.after is None:

@@ -49,22 +49,39 @@ class TransactionalComponent:
         self.active[txn] = NULL_LSN
         return txn
 
-    def _log_op(self, txn: TxnId, table: str, key: bytes,
-                before: Optional[bytes], after: Optional[bytes],
-                op: RecKind) -> UpdateRec:
+    def _append_op(self, txn: TxnId, table: str, key: bytes,
+                   before: Optional[bytes], after: Optional[bytes],
+                   op: RecKind, ck: Optional[bytes] = None) -> UpdateRec:
+        """Append one update record to ``txn``'s chain (not yet applied)."""
         rec = UpdateRec(txn=txn, table=table, key=key, before=before,
-                        after=after, prev_lsn=self.active[txn], op=op)
+                        after=after, prev_lsn=self.active[txn], op=op, ck=ck)
         self.log.append(rec)
         _C_LOG_BYTES.inc(len(before or b"") + len(after or b""))
         self.active[txn] = rec.lsn
         self._first_writes.setdefault(txn, {}).setdefault(
             (table, key), (rec.lsn, before))
+        return rec
+
+    def _log_op(self, txn: TxnId, table: str, key: bytes,
+                before: Optional[bytes], after: Optional[bytes],
+                op: RecKind) -> UpdateRec:
+        rec = self._append_op(txn, table, key, before, after, op)
         self.dc.apply(rec)       # DC stamps rec.pid (prototype common log)
         return rec
 
-    def update(self, txn: TxnId, table: str, key: bytes, value: bytes) -> None:
-        before = self.dc.read(table, key)
-        self._log_op(txn, table, key, before, value, RecKind.UPDATE)
+    def update(self, txn: TxnId, table: str, key: bytes, value: bytes,
+               skip_unchanged: bool = False) -> bool:
+        """Log ``value`` as ``key``'s new value, then apply it: one traversal
+        finds the leaf for both the before-image read and the put.  With
+        ``skip_unchanged`` a value equal to its before-image byte for byte
+        is neither logged nor applied.  Returns whether it was logged."""
+        ck, pid, before = self.dc.read_leaf(table, key)
+        if skip_unchanged and before == value:
+            return False
+        rec = self._append_op(txn, table, key, before, value, RecKind.UPDATE,
+                              ck)
+        self.dc.put_in_leaf(rec, pid)   # DC stamps rec.pid
+        return True
 
     def insert(self, txn: TxnId, table: str, key: bytes, value: bytes) -> None:
         self._log_op(txn, table, key, None, value, RecKind.INSERT)
@@ -174,18 +191,9 @@ class TransactionalComponent:
         in reverse append order, which per key is reverse source order.
         Returns the number of ops applied."""
         order = sorted(shipped_ops, key=rec_key)   # stable: per-key source
-        local: list[UpdateRec] = []                # order is kept
-        log, active = self.log, self.active
-        for s in order:
-            rec = UpdateRec(txn=txn, table=s.table, key=s.key,
-                            before=s.before, after=s.after,
-                            prev_lsn=active[txn], op=s.op, ck=s.ck)
-            log.append(rec)
-            _C_LOG_BYTES.inc(len(s.before or b"") + len(s.after or b""))
-            active[txn] = rec.lsn
-            self._first_writes.setdefault(txn, {}).setdefault(
-                (s.table, s.key), (rec.lsn, s.before))
-            local.append(rec)
+        local = [self._append_op(txn, s.table, s.key, s.before, s.after,
+                                 s.op, s.ck)       # order is kept
+                 for s in order]
         # local LSNs were assigned in sorted-key order, so the batch is
         # presorted for the engine (its sort is then a linear verify)
         self.dc.apply_batch(local, mode="execute")

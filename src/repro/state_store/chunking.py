@@ -33,7 +33,9 @@ def _leaf_paths(tree: Any) -> list[tuple[str, Any]]:
     return out
 
 
-def encode_chunk(arr_bytes: bytes, dtype: str, n: int) -> bytes:
+def encode_chunk(arr_bytes: bytes | memoryview, dtype: str, n: int) -> bytes:
+    """Header + the elements' raw bytes, in one copy of ``arr_bytes``
+    (any contiguous buffer)."""
     return _HDR.pack(_DTYPES.index(dtype), n) + arr_bytes
 
 
@@ -64,7 +66,7 @@ def tree_to_records(tree: Any, chunk_elems: int = CHUNK_ELEMS
             for c in range(n_chunks):
                 part = view[c * chunk_elems:(c + 1) * chunk_elems]
                 key = f"{name}#{c:06d}".encode()
-                yield key, encode_chunk(part.tobytes(), dtype, part.size)
+                yield key, encode_chunk(memoryview(part), dtype, part.size)
 
 
 def records_to_tree(template: Any, records: dict[bytes, bytes],

@@ -26,14 +26,13 @@ from dataclasses import dataclass
 from typing import Any, Callable, Optional
 
 import jax
-import numpy as np
 
 from repro.core import Database, Strategy, recover
 from repro.core.dc import make_key
 from repro.obs import metrics as obs_metrics
 from repro.obs.trace import TRACER
 
-from .chunking import CHUNK_ELEMS, records_to_tree, tree_to_records
+from .chunking import records_to_tree, tree_to_records
 
 META_TABLE = "meta"
 STATE_TABLE = "state"
@@ -64,21 +63,19 @@ class TrainWAL:
                            page_size=self.cfg.page_size)
         self.db.bootstrap_empty()
         self._bootstrapped = False
-        self._digests: dict[bytes, int] = {}     # chunk key -> crc32
 
     # -------------------------------------------------------------- logging
     def log_state(self, step: int, cursor: int, state: Any,
                   delta_only: bool = True) -> None:
         """One transaction: changed state chunks + the metadata record.
-        ``delta_only`` skips chunks whose bytes did not change since the last
-        log_state (embedding rows / routed experts / frozen towers) — the
-        update stream becomes sparse, which is exactly the locality the
-        paper's DPT machinery exploits.  Commit forces the WAL.
+        ``delta_only`` skips chunks whose bytes equal their stored value
+        (embedding rows / routed experts / frozen towers) — the update
+        stream becomes sparse, which is exactly the locality the paper's
+        DPT machinery exploits.  Commit forces the WAL.
 
         Spans (live only under ``obs.enable()`` or a profiler session):
         ``wal.log_state`` over ``wal.save.wait``, the per-leaf spans of
         ``tree_to_records``, ``wal.save.commit`` and ``pool.bg_flush``."""
-        import zlib
         with TRACER.span("wal.log_state", step=step) as sp:
             with TRACER.span("wal.save.wait"):
                 jax.block_until_ready(state)
@@ -87,18 +84,12 @@ class TrainWAL:
             n_upd = n_skip = state_bytes = 0
             for key, value in tree_to_records(state, self.cfg.chunk_elems):
                 state_bytes += len(value)
-                if delta_only and self._bootstrapped:
-                    dig = zlib.crc32(value)
-                    if self._digests.get(key) == dig:
-                        n_skip += 1
-                        continue
-                    self._digests[key] = dig
-                elif delta_only:
-                    self._digests[key] = zlib.crc32(value)
-                if self._bootstrapped:
-                    self.db.tc.update(txn, STATE_TABLE, key, value)
-                else:
+                if not self._bootstrapped:
                     self.db.tc.insert(txn, STATE_TABLE, key, value)
+                elif not self.db.tc.update(txn, STATE_TABLE, key, value,
+                                           skip_unchanged=delta_only):
+                    n_skip += 1
+                    continue
                 n_upd += 1
                 if n_upd % self.cfg.tracker_interval == 0:
                     self.db.dc.emit_trackers()
@@ -167,7 +158,6 @@ class TrainWAL:
         wal.cfg = cfg
         wal.db = db
         wal._bootstrapped = True
-        wal._digests = {}          # rebuilt lazily; first post-restore
         return wal, state, step, cursor, state_step, stats
 
 

@@ -137,3 +137,249 @@ def test_recovery_cost_scales_with_dirty_pages_not_state_size():
     assert s_log1.redo.skipped_dpt > 0
     assert s_log1.io.sync_reads < s_log0.io.sync_reads, \
         (s_log1.io.sync_reads, s_log0.io.sync_reads)
+
+
+# --------------------------------- the update save's one-traversal update
+import dataclasses
+
+from repro import obs
+from repro.core import Database
+from repro.core.records import BWRec, DeltaRec, RecKind, UpdateRec
+from repro.state_store.train_wal import _META, META_TABLE, STATE_TABLE
+
+CH = 1024                               # elements per chunk in these tests
+
+
+def _leaves(k, n_list=0, bf16=True, long=3):
+    """A state of mixed leaves; ``k`` shifts every value, so two values of
+    ``k`` differ in every chunk."""
+    st = {"w": jnp.arange(long * CH + 5, dtype=jnp.float32) + k,
+          "s": jnp.asarray(3 + k, jnp.int32)}
+    if bf16:
+        st["b"] = ((jnp.arange(CH + 9, dtype=jnp.float32) + k) / 7
+                   ).astype(jnp.bfloat16)
+    if n_list:                          # flattened layers/0, 1, 2 ... 11;
+        st["layers"] = [jnp.full((CH // 2 + i,), k + i, jnp.float32)
+                        for i in range(n_list)]     # stored 0, 1, 10, 11, 2
+    return st
+
+
+def _poke(state, path, byte=0):
+    """``state`` with one byte of one leaf flipped (a low mantissa bit)."""
+    leaves, treedef = jax.tree_util.tree_flatten_with_path(state)
+    out = []
+    for p, leaf in leaves:
+        if jax.tree_util.keystr(p) == path:
+            arr = np.array(leaf)
+            arr.view(np.uint8).reshape(-1)[byte] ^= 1
+            leaf = jnp.asarray(arr)
+        out.append(leaf)
+    return jax.tree_util.tree_unflatten(treedef, out)
+
+
+def _read_then_apply(tc, txn, table, key, value):
+    """An update as a read, then a logged and applied op: two traversals,
+    the generic ``dc.apply`` path."""
+    before = tc.dc.read(table, key)
+    tc._log_op(txn, table, key, before, value, RecKind.UPDATE)
+
+
+def _reference_update_save(wal, step, state, delta_only):
+    """The update save as one read-then-apply update per chunk, with the
+    Delta and BW records every ``tracker_interval`` updates."""
+    tc, dc, cfg = wal.db.tc, wal.db.dc, wal.cfg
+    txn = tc.begin()
+    n = 0
+    for key, value in tree_to_records(state, cfg.chunk_elems):
+        if delta_only and dc.read(STATE_TABLE, key) == value:
+            continue
+        _read_then_apply(tc, txn, STATE_TABLE, key, value)
+        n += 1
+        if n % cfg.tracker_interval == 0:
+            dc.emit_trackers()
+    _read_then_apply(tc, txn, META_TABLE, b"latest",
+                     _META.pack(step, step, step))
+    tc.commit(txn)
+    dc.emit_trackers()
+    wal.db.log.flush()
+    dc.maybe_background_flush(cfg.bg_flush_pages)
+
+
+def _unordered(rec):
+    """Delta and BW records compare as sets of pages."""
+    if isinstance(rec, DeltaRec):
+        return dataclasses.replace(rec, dirty_set=sorted(rec.dirty_set),
+                                   written_set=sorted(rec.written_set))
+    if isinstance(rec, BWRec):
+        return dataclasses.replace(rec, written_set=sorted(rec.written_set))
+    return rec
+
+
+def _every_other_layer():
+    """``_leaves(0, n_list=10)`` with layers 0, 2, 4, 6, 8 changed."""
+    st = _leaves(0, n_list=10)
+    new = _leaves(1, n_list=10)["layers"]
+    st["layers"] = [new[i] if i % 2 == 0 else leaf
+                    for i, leaf in enumerate(st["layers"])]
+    return st
+
+
+_EQUIV_CASES = {
+    # name: (states before and saved, delta_only, tracker_interval)
+    "dense": (lambda: (_leaves(0, bf16=False), _leaves(1, bf16=False)),
+              False, 4),
+    "sparse": (lambda: (_leaves(0, n_list=3), _poke(_poke(
+        _leaves(0, n_list=3), "['w']", 4 * 2 * CH), "['layers'][1]")),
+        True, 4),
+    "fp32_bf16": (lambda: (_leaves(0), _leaves(1)), True, 3),
+    "half_the_leaves": (lambda: (_leaves(0, n_list=10),
+                                 _every_other_layer()), True, 3),
+    "leaf_across_trackers": (lambda: (_leaves(0, long=11),
+                                      _leaves(-1, long=11)), True, 5),
+    "list_of_12_leaves": (lambda: (_leaves(0, n_list=12),
+                                   _leaves(2, n_list=12)), True, 7),
+}
+
+
+@pytest.mark.parametrize("checkpoint_first", [False, True],
+                         ids=["dirty_pages", "after_checkpoint"])
+@pytest.mark.parametrize("case", list(_EQUIV_CASES))
+def test_update_save_logs_what_update_per_chunk_logs(case, checkpoint_first):
+    states, delta_only, interval = _EQUIV_CASES[case]
+    before, saved = states()
+    cfg = WALConfig(chunk_elems=CH, tracker_interval=interval,
+                    cache_pages=4096, bg_flush_pages=2)
+    wals = [TrainWAL(cfg), TrainWAL(cfg)]
+    for w in wals:
+        w.log_state(0, 0, before)
+        if checkpoint_first:
+            w.db.checkpoint()
+    lo = wals[0].db.log.end_lsn
+    assert lo == wals[1].db.log.end_lsn
+    wals[0].log_state(1, 1, saved, delta_only=delta_only)
+    _reference_update_save(wals[1], 1, saved, delta_only)
+    got, want = wals[0].db.log, wals[1].db.log
+    assert got.end_lsn == want.end_lsn
+    n_upd = n_trackers = 0
+    for lsn in range(lo + 1, want.end_lsn + 1):
+        a, b = got.record(lsn), want.record(lsn)
+        assert type(a) is type(b), lsn
+        # an update: kind, key, before- and after-image, txn, prev_lsn, pid
+        assert _unordered(a) == _unordered(b), lsn
+        n_upd += isinstance(b, UpdateRec) and b.table == STATE_TABLE
+        n_trackers += isinstance(b, DeltaRec)
+    if case == "sparse":
+        assert n_upd == 2
+    elif case == "half_the_leaves":
+        assert n_upd == 5
+    else:
+        assert n_upd == len(list(tree_to_records(saved, CH)))
+        assert n_trackers > n_upd // interval >= 1
+    assert dict(wals[0].db.scan_all()) == dict(wals[1].db.scan_all())
+
+
+def test_update_matches_read_then_apply_through_splits():
+    """Values that grow split leaves: ``tc.update`` falls back to the
+    ordinary put there and leaves the log and tree that a read, then a
+    logged and applied op, leave; ``skip_unchanged`` logs nothing."""
+    rows = [(b"k%03d" % i, b"v" * 40) for i in range(60)]
+    grown = [(k, bytes([65 + i % 26]) * (40 + 7 * i)) for i, (k, _) in
+             enumerate(rows)]
+    dbs = []
+    for one_traversal in (True, False):
+        db = Database(cache_pages=256, page_size=1024)
+        db.bootstrap_empty()
+        db.run_txn([("insert", "t", k, v) for k, v in rows])
+        txn = db.tc.begin()
+        for k, v in grown:
+            if one_traversal:
+                assert db.tc.update(txn, "t", k, v, skip_unchanged=True)
+            else:
+                _read_then_apply(db.tc, txn, "t", k, v)
+        if one_traversal:
+            assert not any(db.tc.update(txn, "t", k, v, skip_unchanged=True)
+                           for k, v in grown[:5])
+        db.tc.commit(txn)
+        dbs.append(db)
+    a, b = dbs[0].log, dbs[1].log
+    assert a.end_lsn == b.end_lsn
+    assert [a.record(i) for i in range(1, a.end_lsn + 1)] == \
+        [b.record(i) for i in range(1, b.end_lsn + 1)]
+    assert dbs[0].dc.btree.smo_count == dbs[1].dc.btree.smo_count > 0
+    assert dbs[0].scan_all() == dbs[1].scan_all()
+    assert dict(dbs[0].dc.scan_range("t")) == dict(grown)
+
+
+@pytest.mark.parametrize("strategy", [Strategy.LOG0, Strategy.LOG1,
+                                      Strategy.LOG2])
+def test_crash_inside_an_update_save_recovers_the_last_commit(
+        strategy, monkeypatch):
+    cfg = WALConfig(chunk_elems=CH, tracker_interval=3, cache_pages=4096,
+                    bg_flush_pages=4)
+    wal = TrainWAL(cfg)
+    committed = _leaves(1, n_list=3)
+    wal.log_state(0, 0, _leaves(0, n_list=3))
+    wal.log_state(1, 1, committed)
+    want = dict(tree_to_records(committed, CH))
+    tc = wal.db.tc
+    update = tc.update
+    seen = {"calls": 0}
+
+    def crash_after_seven(txn, table, key, value, skip_unchanged=False):
+        out = update(txn, table, key, value, skip_unchanged)
+        seen["calls"] += 1
+        if seen["calls"] == 7:                      # past two tracker points
+            wal.db.log.flush()                      # these updates stable
+            wal.db.dc.maybe_background_flush(64)    # and some of the pages
+            for key, value in want.items():
+                assert tc.committed_read(STATE_TABLE, key) == value
+            assert any(wal.db.dc.read(STATE_TABLE, k) != v
+                       for k, v in want.items())
+            seen["image"] = wal.crash()
+            seen["txn"] = txn
+        return out
+    monkeypatch.setattr(tc, "update", crash_after_seven)
+    wal.log_state(2, 2, _leaves(2, n_list=3))
+    assert seen["calls"] > 7
+    image = seen["image"]
+    stable = [r for r in image.log.scan(1)
+              if isinstance(r, UpdateRec) and r.txn == seen["txn"]]
+    assert 0 < len(stable) < len(want)
+    _, restored, step, cursor, state_step, stats = TrainWAL.restore(
+        image, jax.eval_shape(lambda: committed), cfg, strategy)
+    assert (step, cursor, state_step) == (1, 1, 1)
+    assert stats.losers == 1 and stats.undone_ops == len(stable)
+    for got, exp in zip(jax.tree.leaves(restored),
+                        jax.tree.leaves(committed)):
+        assert got.dtype == exp.dtype
+        assert np.asarray(got).tobytes() == np.asarray(exp).tobytes()
+
+
+def test_delta_only_after_restore_logs_just_the_changed_chunk():
+    cfg = WALConfig(chunk_elems=CH, tracker_interval=4, cache_pages=4096)
+    wal = TrainWAL(cfg)
+    state = _leaves(0, n_list=2)
+    wal.log_state(0, 0, state)
+    wal.log_state(1, 1, _leaves(1, n_list=2))
+    wal2, restored, *_ = TrainWAL.restore(
+        wal.crash(), jax.eval_shape(lambda: state), cfg)
+    chunks = dict(tree_to_records(restored, CH))
+    log = wal2.db.log
+
+    def save(step, st):
+        """The state chunks one save logs, and ``log.bytes_appended``'s
+        reading less the meta record's before- and after-image."""
+        lo, b0 = log.end_lsn, obs.value("log.bytes_appended")
+        wal2.log_state(step, step, st)
+        recs = [log.record(i) for i in range(lo + 1, log.end_lsn + 1)]
+        keys = [r.key for r in recs if isinstance(r, UpdateRec)
+                and r.table == STATE_TABLE]
+        return keys, obs.value("log.bytes_appended") - b0 - 2 * _META.size
+
+    keys, state_bytes = save(2, restored)
+    assert keys == [] and state_bytes == 0
+    keys, state_bytes = save(3, _poke(restored, "['layers'][1]", 5))
+    assert keys == [b"layers/1#000000"]
+    assert state_bytes == 2 * len(chunks[b"layers/1#000000"])
+    keys, _ = save(4, _poke(restored, "['w']", 4 * 2 * CH + 1))
+    assert keys == [b"layers/1#000000", b"w#000002"]
