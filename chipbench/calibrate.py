@@ -29,7 +29,7 @@ from chipbench.reflib import NUMBERS  # noqa: E402
 
 def program_readings(tr, seed: int, step_fn=None) -> dict:
     from chipbench import traffic
-    tr.state = traffic.make_state(seed, tr.shapes)
+    tr.state = traffic.make_state(seed, tr.shapes, tr.cfg.get("init"))
     tr.feed = traffic.make_feed(seed, tr.cfg["job"], tr.program_cfg.vocab_size,
                                 tr.program_cfg.d_model, tr.program_cfg.dtype)
     step = tr.step

@@ -1,6 +1,11 @@
 """Model operations of one training step, counted matmul by matmul from the
 shapes in a configuration's JSON file.
 
+Each family's count is a file of its own, ``chipbench/counts/<model_type>.py``,
+found by the configuration's ``model_type`` as a per-layer metric's reader
+is found by its name.  It holds ``forward(c, job, causal="mask")``: the
+forward operations of one step of ``job``, built from the helpers here.
+
 Conventions:
 
 - A matmul of (m, k) by (k, n) is 2·m·k·n operations.  Norms, softmax,
@@ -12,11 +17,13 @@ Conventions:
   for scores and for values alike.  ``causal="full"`` counts S² pairs, which
   is what a program that masks a full score matrix computes; the test
   checks that form against the program's jaxpr.
-- Encoder-decoder: the encoder over its frames; the decoder over its tokens,
-  with cross-attention K/V projected over the frames and scores over
-  tokens × frames; the tied output head over every decoder position.
 """
 from __future__ import annotations
+
+import importlib.util
+from pathlib import Path
+
+COUNTS = Path(__file__).resolve().parent / "counts"
 
 
 def _pairs(s: int, causal: str) -> float:
@@ -32,40 +39,18 @@ def _attn(tokens: int, kv_tokens: int, d: int, q_width: int, kv_width: int,
             + 2 * tokens * q_width * d)          # output
 
 
-def forward_whisper(c: dict, batch: int, seq: int, frames: int,
-                    causal: str = "mask") -> float:
-    d, v = c["d_model"], c["vocab_size"]
-    enc = c["encoder_layers"] * (
-        _attn(frames, frames, d, d, d, frames * frames, d)
-        + 2 * 2 * frames * d * c["encoder_ffn_dim"])
-    dec = c["decoder_layers"] * (
-        _attn(seq, seq, d, d, d, _pairs(seq, causal), d)          # self
-        + _attn(seq, frames, d, d, d, seq * frames, d)            # cross
-        + 2 * 2 * seq * d * c["decoder_ffn_dim"])
-    head = 2 * seq * d * v
-    return batch * (enc + dec + head)
-
-
-def forward_qwen2(c: dict, batch: int, seq: int, causal: str = "mask"
-                  ) -> float:
-    d, v = c["hidden_size"], c["vocab_size"]
-    hd = c.get("head_dim") or d // c["num_attention_heads"]
-    qw = c["num_attention_heads"] * hd
-    kvw = c["num_key_value_heads"] * hd
-    layer = (_attn(seq, seq, d, qw, kvw, _pairs(seq, causal), qw)
-             + 3 * 2 * seq * d * c["intermediate_size"])          # SwiGLU
-    head = 2 * seq * d * v
-    return batch * (c["num_hidden_layers"] * layer + head)
-
-
-FORWARD = {"whisper": forward_whisper, "qwen2": forward_qwen2}
-
-
 def forward_flops(c: dict, job: dict, causal: str = "mask") -> float:
-    fn = FORWARD[c["model_type"]]
-    if c["model_type"] == "whisper":
-        return fn(c, job["batch"], job["seq"], job["frames"], causal)
-    return fn(c, job["batch"], job["seq"], causal)
+    family = c["model_type"]
+    path = COUNTS / f"{family}.py"
+    if not path.is_file():
+        raise FileNotFoundError(
+            f"no operation count for model_type {family!r}: add "
+            f"chipbench/counts/{family}.py with forward(c, job, causal)")
+    spec = importlib.util.spec_from_file_location(
+        f"chipbench_count_{family}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.forward(c, job, causal)
 
 
 def train_step_flops(c: dict, job: dict) -> float:

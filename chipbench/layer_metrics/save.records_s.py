@@ -1,7 +1,8 @@
 """Host time per save spent turning the state into log records: the
-program's ``wal.save.records`` spans (each leaf's chunk loop: crc32,
-encoding, one ``tc.update`` per chunk, trackers) over its
-``wal.log_state`` spans, in s."""
+program's ``wal.save.records`` spans (each leaf's chunk loop: one copy of
+each chunk into its record, then ``tc.update`` with one traversal to the
+leaf, the before-image compare, the log append and the put; the trackers)
+over its ``wal.log_state`` spans, in s."""
 from chipbench import program_spans
 
 

@@ -173,7 +173,7 @@ def build_trainer(cfg: dict, seed: int) -> Trainer:
         raise ValueError(f"program dtype {pc.dtype} != job {job['dtype']}")
     api = build_model(pc)
     shapes = jax.eval_shape(api.init, jax.random.PRNGKey(0))
-    state = traffic.make_state(seed, shapes)
+    state = traffic.make_state(seed, shapes, cfg.get("init"))
     hp = dict(cfg["optimizer"], betas=tuple(cfg["optimizer"]["betas"]))
     feed = traffic.make_feed(seed, job, pc.vocab_size, pc.d_model, pc.dtype)
     step = make_train_step(api, AdamWConfig(**hp))
@@ -438,7 +438,7 @@ def reference_readings(tr: Trainer, seed: int, mm: str = "f32",
     from chipbench import reflib, traffic
     ref = load_module(Path(tr.cfg["_reference"]),
                       f"chipbench_ref_{tr.cfg['name']}")
-    params0 = traffic.make_params(seed, tr.shapes)
+    params0 = traffic.make_params(seed, tr.shapes, tr.cfg.get("init"))
     return reflib.run_steps(ref.loss, tr.cfg, params0, tr.feed, 3, mm=mm,
                             rows=rows)
 

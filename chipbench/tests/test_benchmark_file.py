@@ -82,3 +82,10 @@ def test_cells_metrics_and_layers():
         assert "setup_s" in reported and len(reported) >= 2
         assert any(w in m["workloads"] for m in BENCH["per_layer"])
     assert len(json.dumps(BENCH)) < 64 * 1024
+
+
+def test_every_configuration_has_its_operation_count():
+    for c in BENCH["configs"]:
+        family = json.loads((ROOT / c["file"]).read_text())["model_type"]
+        count = ROOT / "chipbench" / "counts" / f"{family}.py"
+        assert count.is_file(), f"{c['name']}: no {count}"

@@ -1,10 +1,12 @@
-"""A later change adds a mix, a configuration or a per-layer metric by
-adding files and entries: the harness finds them by name, and no file that
-is already there changes."""
+"""A later change adds a mix, a configuration, a model family's operation
+count or a per-layer metric by adding files and entries: the harness finds
+them by name, and no file that is already there changes."""
+import json
 import shutil
 
 import pytest
 
+from chipbench import flops, traffic
 from chipbench import run as R
 
 
@@ -16,6 +18,7 @@ def copy_of_chipbench(tmp_path, monkeypatch):
     before = {p: p.read_bytes() for p in (root / "chipbench").rglob("*")
               if p.is_file()}
     monkeypatch.setattr(R, "HERE", root / "chipbench")
+    monkeypatch.setattr(flops, "COUNTS", root / "chipbench" / "counts")
     yield root / "chipbench"
     for p, data in before.items():
         assert p.read_bytes() == data, f"{p} changed"
@@ -49,6 +52,34 @@ def test_new_mix_and_metric_found_by_name(copy_of_chipbench, fresh_bench,
     assert out["correct"], out["checks"]
     assert out["counts"]["steps"] == 10
     assert "train_tokens_per_s" in out["metrics"]
+
+
+def test_new_family_count_and_init_found_by_name(copy_of_chipbench):
+    import jax
+    import jax.numpy as jnp
+    here = copy_of_chipbench
+    (here / "counts" / "toy_family.py").write_text(
+        "def forward(c, job, causal='mask'):\n"
+        "    return 2.0 * job['batch'] * job['seq'] * c['hidden_size'] ** 2\n")
+    (here / "configs" / "toy.json").write_text(json.dumps(
+        {"name": "toy", "model_type": "toy_family", "hidden_size": 8,
+         "job": {"batch": 2, "seq": 4},
+         "init": {"router/score_bias": "ones", "gate": {"normal": 0.5}}}))
+    cfg = json.loads((here / "configs" / "toy.json").read_text())
+    assert flops.train_step_flops(cfg, cfg["job"]) == 3.0 * 2.0 * 2 * 4 * 64
+    shapes = {"router": {"score_bias": jax.ShapeDtypeStruct((8,), jnp.float32),
+                         "w": jax.ShapeDtypeStruct((8, 8), jnp.bfloat16)},
+              "gate": jax.ShapeDtypeStruct((), jnp.float32)}
+    seed = 2**35 + 7
+    params = traffic.make_params(seed, shapes, cfg["init"])
+    assert params["router"]["score_bias"].dtype == jnp.float32
+    assert jnp.array_equal(params["router"]["score_bias"], jnp.ones(8))
+    # leaves in flattening order: gate, router/score_bias, router/w
+    key = jax.random.fold_in(jax.random.fold_in(traffic.seed_key(seed), 0), 0)
+    assert jnp.array_equal(params["gate"],
+                           jax.random.normal(key, (), jnp.float32) * 0.5)
+    with pytest.raises(ValueError, match="router/score_bias"):
+        traffic.make_params(seed, shapes, {"gate": "zeros"})
 
 
 def test_metric_reader_that_finds_nothing_is_left_out(copy_of_chipbench):

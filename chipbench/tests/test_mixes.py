@@ -56,3 +56,64 @@ def test_same_seed_same_inputs_and_weights():
     assert not jnp.array_equal(f(3)["tokens"], f(4)["tokens"])
     t = f(0)["tokens"]
     assert len({tuple(r) for r in t.tolist()}) == 2       # rows differ
+
+
+def _old_rule_params(seed: int, shapes):
+    """``traffic.make_params`` as it was before the ``init`` map: the
+    reference the shared rules are held to, bit for bit."""
+    import math
+
+    import jax
+    import jax.numpy as jnp
+
+    from chipbench.traffic import leaf_name, seed_key
+
+    def init_leaf(key, name, shape, dtype):
+        last = name.rsplit("/", 1)[-1]
+        if last == "scale":
+            return jnp.ones(shape, dtype)
+        if last in ("bias", "bq", "bk", "bv"):
+            return jnp.zeros(shape, dtype)
+        if last == "embed" or name == "embed":
+            std = 0.02
+        else:
+            std = 1.0 / math.sqrt(shape[-2])
+        return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
+
+    leaves, treedef = jax.tree_util.tree_flatten_with_path(shapes)
+    spec = [(leaf_name(p), tuple(s.shape), s.dtype) for p, s in leaves]
+
+    def build(key):
+        key = jax.random.fold_in(key, 0)
+        return jax.tree_util.tree_unflatten(treedef, [
+            init_leaf(jax.random.fold_in(key, i), n, sh, dt)
+            for i, (n, sh, dt) in enumerate(spec)])
+
+    return jax.jit(build)(seed_key(seed))
+
+
+@pytest.mark.parametrize("name", ["tiny_whisper", "tiny_qwen2"])
+def test_weights_are_the_old_rules_bit_for_bit(bench, name):
+    import jax
+    import jax.numpy as jnp
+
+    from chipbench import traffic
+    cfg = R.find_cell(bench, f"{name}.save10")[1]
+    assert "init" not in cfg
+    shapes = R.build_trainer(cfg, 0).shapes
+    seed = 2**36 + 5
+    new = traffic.make_params(seed, shapes, cfg.get("init"))
+    old = _old_rule_params(seed, shapes)
+    for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(new)[0],
+                            jax.tree.leaves(old)):
+        assert a.dtype == b.dtype and jnp.array_equal(a, b), path
+
+
+def test_init_entry_of_another_form_is_refused():
+    import jax
+    import jax.numpy as jnp
+
+    from chipbench import traffic
+    shapes = {"w": jax.ShapeDtypeStruct((4, 4), jnp.float32)}
+    with pytest.raises(ValueError, match="'w'"):
+        traffic.make_params(1, shapes, {"w": "uniform"})

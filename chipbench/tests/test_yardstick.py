@@ -11,6 +11,7 @@ from chipbench import flops, trace_reduce
 from chipbench.peaks import peaks
 
 DATA = Path(__file__).parent / "data"
+CONFIGS = Path(__file__).parents[1] / "configs"
 
 
 def _dot_flops(jaxpr, mult=1.0) -> float:
@@ -73,6 +74,24 @@ def test_flops_causal_convention_counts_kept_pairs():
     d = cfg["num_attention_heads"] * cfg["assumed"]["head_dim"]
     per_pair = 2 * 2 * d * job["batch"] * cfg["num_hidden_layers"]
     assert full - kept == pytest.approx(per_pair * (64 - 36))
+
+
+# train_step_flops as the parent computed it, before the counts moved to
+# chipbench/counts/: a move that changes a digit changes every step_mfu
+PARENT_STEP_FLOPS = {"whisper_base": 3521397129216.0,
+                     "qwen2_5_3b_l1": 11812435132416.0}
+
+
+@pytest.mark.parametrize("name", sorted(PARENT_STEP_FLOPS))
+def test_train_step_flops_are_the_parents_to_the_last_digit(name):
+    cfg = json.loads((CONFIGS / f"{name}.json").read_text())
+    assert flops.train_step_flops(cfg, cfg["job"]) == PARENT_STEP_FLOPS[name]
+
+
+def test_a_family_without_a_count_names_the_file_to_add():
+    with pytest.raises(FileNotFoundError,
+                       match="chipbench/counts/no_such_family.py"):
+        flops.forward_flops({"model_type": "no_such_family"}, {})
 
 
 def test_peaks_refuse_an_unknown_device_kind():
