@@ -21,6 +21,19 @@ where a record physically lives.  Only records *pruned from the archive*
 are gone for good — reading below ``retained_lsn`` raises
 ``TruncatedLogError`` (never a silent skip), which the shipper surfaces as
 ``SnapshotRequired``.
+
+Truncation without an archive is ``discard(upto)``: a log whose one
+reader is crash recovery may delete the stable prefix below the last
+complete checkpoint's bCkpt record.  Recovery scans from that record
+(``recover`` reads the master's ``bckpt_lsn``, and the RSSP and EndCkpt
+records above it), the RSSP checkpoint has flushed every page dirtied
+before it, and with no transaction active a loser's undo chain starts
+above the cut too; so nothing a recovery reads is lost.  The records go
+for good: a read below the base raises ``TruncatedLogError``.
+``discard`` refuses when an archive is attached (then ``truncate``
+through the ``Archiver`` is the path) and when the cut reaches the bCkpt
+record or the unstable tail; the TC adds the last guard, no active
+transaction (``TransactionalComponent.discard_log``).
 """
 from __future__ import annotations
 
@@ -30,7 +43,7 @@ from dataclasses import dataclass, field
 from typing import Iterator, List, Optional, Tuple
 
 from .records import (LSN, NULL_LSN, BeginCkptRec, CommitRec, EndCkptRec,
-                      LogRec, RSSPRec)
+                      LogRec, RSSPRec, UpdateRec)
 
 # Purely for IO accounting: how many log records fit a "log page".
 LOG_RECS_PER_PAGE = 64
@@ -38,6 +51,15 @@ LOG_RECS_PER_PAGE = 64
 #: commit-to-visible stamp retention; bounds memory on a primary whose
 #: replicas never poll (stamps for drained commits are long gone anyway)
 _MAX_COMMIT_STAMPS = 8192
+
+
+def _image_bytes(rec: UpdateRec) -> int:
+    return len(rec.before or b"") + len(rec.after or b"")
+
+
+def _held_image_bytes(recs) -> int:
+    """``_image_bytes`` summed over the update records of ``recs``."""
+    return sum(_image_bytes(r) for r in recs if isinstance(r, UpdateRec))
 
 
 class TruncatedLogError(LookupError):
@@ -82,11 +104,19 @@ class LogManager:
         # Bounded FIFO: insertion order is LSN order, so evicting the
         # oldest drops the commit least likely to still be in flight.
         self.commit_stamps: dict = {}
+        # Before- plus after-image bytes of the update records held in
+        # memory: raised on append, lowered when a prefix leaves memory.
+        # A CLR's after-image is the undone update's before-image object,
+        # so CLRs add nothing; summed over appends this is what the
+        # ``log.bytes_appended`` counter adds for this log.
+        self.live_image_bytes: int = 0
 
     # ---------------------------------------------------------------- append
     def append(self, rec: LogRec) -> LSN:
         rec.lsn = self._base + len(self._recs) + 1   # dense LSNs starting at 1
         self._recs.append(rec)
+        if isinstance(rec, UpdateRec):
+            self.live_image_bytes += _image_bytes(rec)
         txn = getattr(rec, "txn", None)
         if txn is not None and txn > self.max_txn:
             self.max_txn = txn
@@ -166,8 +196,36 @@ class LogManager:
                 f"cannot truncate through LSN {upto}: {have} — seal the "
                 "prefix into a LogArchive first (truncation moves records, "
                 "it never deletes them)")
+        return self._drop_prefix(upto)
+
+    def discard(self, upto: LSN) -> int:
+        """Delete the in-memory prefix [1, upto] for good; returns records
+        dropped.  Only for a log whose one reader is crash recovery, and
+        only below the last complete checkpoint's bCkpt record, where
+        recovery's scan starts (module docstring).  Refuses an attached
+        archive, a cut at or above the master's ``bckpt_lsn``, and a cut
+        above the stable point."""
+        if self.archive is not None:
+            raise ValueError(
+                f"cannot discard through LSN {upto}: an archive is attached "
+                "— truncate through the Archiver, which keeps the records")
+        if upto >= self.master.bckpt_lsn:
+            raise ValueError(
+                f"cannot discard through LSN {upto}: recovery scans from the "
+                f"last complete checkpoint's bCkpt at LSN "
+                f"{self.master.bckpt_lsn}")
+        if upto > self._stable_lsn:
+            raise ValueError(
+                f"cannot discard through LSN {upto}: the log is stable only "
+                f"through LSN {self._stable_lsn}")
+        if upto <= self._base:
+            return 0
+        return self._drop_prefix(upto)
+
+    def _drop_prefix(self, upto: LSN) -> int:
         dropped = upto - self._base
-        self._recs = self._recs[dropped:]
+        self.live_image_bytes -= _held_image_bytes(self._recs[:dropped])
+        del self._recs[:dropped]
         self._base = upto
         return dropped
 
@@ -265,7 +323,10 @@ class LogManager:
         sealed segments, so a post-truncation crash image still reads the
         full history through the splice."""
         survivor = LogManager()
-        survivor._recs = self._recs[: self._stable_lsn - self._base]
+        kept = self._stable_lsn - self._base
+        survivor._recs = self._recs[:kept]
+        survivor.live_image_bytes = (self.live_image_bytes
+                                     - _held_image_bytes(self._recs[kept:]))
         survivor._base = self._base
         survivor._stable_lsn = self._stable_lsn
         survivor.archive = self.archive

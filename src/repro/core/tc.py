@@ -256,6 +256,16 @@ class TransactionalComponent:
         self.log.set_master(end_ckpt=e.lsn, bckpt=b.lsn)
         return b.lsn
 
+    def discard_log(self, upto: LSN) -> int:
+        """``LogManager.discard`` with the TC's guard: refused while any
+        transaction is active, whose undo chain could reach below the
+        cut.  Returns records dropped."""
+        if self.active:
+            raise ValueError(
+                f"cannot discard the log through LSN {upto}: transactions "
+                f"{sorted(self.active)} are active")
+        return self.log.discard(upto)
+
     # -------------------------------------------------------------- snapshot
     def snapshot_begin(self, snapshot_id: int = 0) -> SnapshotRec:
         """Anchor a fuzzy logical snapshot: log (and force) a SnapshotRec

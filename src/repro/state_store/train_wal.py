@@ -1,7 +1,7 @@
 """TrainWAL: the paper's logical recovery as the framework's fault-tolerance
 layer.
 
-Roles (mirroring DESIGN.md's mapping):
+Roles:
   TC  = the training coordinator: logs *logical* records — per-step metadata
         (step id, data cursor) every step, and state-chunk after-images every
         ``chunk_interval`` steps (an incremental, fuzzy checkpoint).  It
@@ -18,6 +18,12 @@ Recovery after a crash:
      the data pipeline is counter-based, so the logged cursor + deterministic
      train_step reproduce them exactly — the training-world analogue of the
      "tail of the log" falling back to op re-execution.
+
+The log is cut at each checkpoint: ``maybe_checkpoint`` discards every
+record below the new checkpoint's bCkpt record, where recovery's scan
+starts (``LogManager.discard``).  Its one reader is crash recovery, so
+the host holds only the records since the last checkpoint, and the
+images of the saves before it are freed for the next saves to reuse.
 """
 from __future__ import annotations
 
@@ -75,7 +81,9 @@ class TrainWAL:
 
         Spans (live only under ``obs.enable()`` or a profiler session):
         ``wal.log_state`` over ``wal.save.wait``, the per-leaf spans of
-        ``tree_to_records``, ``wal.save.commit`` and ``pool.bg_flush``."""
+        ``tree_to_records``, ``wal.save.commit`` and ``pool.bg_flush``;
+        ``wal.log_state`` carries ``log_live_bytes``, the image bytes the
+        log holds in memory at the save's commit."""
         with TRACER.span("wal.log_state", step=step) as sp:
             with TRACER.span("wal.save.wait"):
                 jax.block_until_ready(state)
@@ -107,7 +115,8 @@ class TrainWAL:
             self.db.dc.maybe_background_flush(self.cfg.bg_flush_pages)
             sp.set(chunks_written=n_upd, chunks_skipped=n_skip,
                    log_bytes=_C_LOG_BYTES.value - log0,
-                   state_bytes=state_bytes)
+                   state_bytes=state_bytes,
+                   log_live_bytes=self.db.log.live_image_bytes)
 
     def log_step_meta(self, step: int, cursor: int, state_step: int) -> None:
         """Per-step heartbeat: step id + data cursor (tiny txn)."""
@@ -119,8 +128,17 @@ class TrainWAL:
             self.db.dc.maybe_background_flush(self.cfg.bg_flush_pages)
 
     def maybe_checkpoint(self, step: int) -> bool:
+        """Every ``ckpt_interval`` steps, between transactions: an RSSP
+        checkpoint, then the log below its bCkpt record is discarded
+        (span ``wal.log.discard``, attributes ``records`` and ``bytes``,
+        the before- and after-image bytes dropped)."""
         if step % self.cfg.ckpt_interval == 0 and step > 0:
-            self.db.checkpoint()
+            bckpt = self.db.checkpoint()
+            log = self.db.log
+            with TRACER.span("wal.log.discard") as sp:
+                live = log.live_image_bytes
+                n = self.db.tc.discard_log(bckpt - 1)
+                sp.set(records=n, bytes=live - log.live_image_bytes)
             return True
         return False
 

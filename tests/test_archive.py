@@ -16,8 +16,9 @@ import pytest
 
 from repro.archive import (Archiver, LogArchive, SnapshotRequired,
                            SnapshotStore)
-from repro.core import (Database, Strategy, TruncatedLogError,
+from repro.core import (Database, LogManager, Strategy, TruncatedLogError,
                         committed_state_oracle, make_key, recover)
+from repro.core.records import BeginCkptRec
 from repro.media import DirectoryBackend, MemoryBackend
 from repro.replication import (LogShipper, Replica, ReplicaSet,
                                ShardedApplier, range_partitioner)
@@ -85,6 +86,53 @@ def test_truncate_guards(primary, make_backend):
         db.log.truncate(30)
     assert db.log.truncate(20) == 20
     assert db.log.truncate(20) == 0          # idempotent
+
+
+def test_discard_guards(primary, make_backend):
+    """Discard deletes below the last checkpoint's bCkpt record only, with
+    no transaction active and no archive attached; recovery from the cut
+    log gives the committed state, and reads below the cut are loud."""
+    rng, db, _, base = primary
+    db.checkpoint()
+    _mix(rng, db, 20)
+    bckpt = db.checkpoint()
+    _mix(rng, db, 10)
+    want = committed_state_oracle(db, base)
+    for upto in (bckpt, bckpt + 1, db.log.stable_lsn):
+        with pytest.raises(ValueError, match="bCkpt"):
+            db.tc.discard_log(upto)
+    txn = db.tc.begin()
+    db.tc.update(txn, "t", b"k00001", b"INFLIGHT")
+    with pytest.raises(ValueError, match="active"):
+        db.tc.discard_log(bckpt - 1)
+    db.tc.abort(txn)
+    assert db.log.retained_lsn == 1
+    assert db.tc.discard_log(bckpt - 1) == bckpt - 1
+    assert db.tc.discard_log(bckpt - 1) == 0         # idempotent
+    assert db.log.retained_lsn == bckpt
+    with pytest.raises(TruncatedLogError):
+        db.log.record(bckpt - 1)
+    with pytest.raises(TruncatedLogError):
+        list(db.log.scan(1))
+    image = db.crash()
+    for strategy in (Strategy.LOG1, Strategy.SQL1):
+        rec_db, stats = recover(image, strategy, page_size=4096)
+        assert stats.scan_from == bckpt
+        assert dict(rec_db.scan_all()) == want
+    db.log.attach_archive(LogArchive(backend=make_backend()))
+    with pytest.raises(ValueError, match="archive is attached"):
+        db.log.discard(bckpt - 1)
+
+
+def test_discard_refuses_the_unstable_tail():
+    log = LogManager()
+    for _ in range(10):
+        log.append(BeginCkptRec())
+    log.flush(upto=5)
+    log.set_master(bckpt=9, end_ckpt=10)
+    with pytest.raises(ValueError, match="stable only through LSN 5"):
+        log.discard(7)
+    assert log.discard(5) == 5 and log.retained_lsn == 6
 
 
 def test_prune_loses_history_loudly(primary, make_backend):

@@ -143,7 +143,7 @@ def test_recovery_cost_scales_with_dirty_pages_not_state_size():
 import dataclasses
 
 from repro import obs
-from repro.core import Database
+from repro.core import Database, TruncatedLogError
 from repro.core.records import BWRec, DeltaRec, RecKind, UpdateRec
 from repro.state_store.train_wal import _META, META_TABLE, STATE_TABLE
 
@@ -383,3 +383,158 @@ def test_delta_only_after_restore_logs_just_the_changed_chunk():
     assert state_bytes == 2 * len(chunks[b"layers/1#000000"])
     keys, _ = save(4, _poke(restored, "['w']", 4 * 2 * CH + 1))
     assert keys == [b"layers/1#000000", b"w#000002"]
+
+
+# ------------------------------------ the log cut at each checkpoint
+def _image_bytes_held(log) -> int:
+    """Before- plus after-image bytes of the update records in memory,
+    counted record by record."""
+    return sum(len(r.before or b"") + len(r.after or b"")
+               for r in log.scan(log.retained_lsn)
+               if isinstance(r, UpdateRec))
+
+
+@pytest.mark.parametrize("strategy", [Strategy.LOG0, Strategy.LOG1,
+                                      Strategy.LOG2])
+def test_resume_after_discards_is_bit_exact(strategy):
+    """Three checkpoint periods, each cutting the log, then a crash after
+    the save that follows the last cut: the resumed state is the lost one,
+    bit for bit."""
+    train_step, state0, batch_at = _tiny_trainer()
+    wal_cfg = WALConfig(chunk_interval=2, ckpt_interval=4, bg_flush_pages=4,
+                        cache_pages=512, chunk_elems=8192,
+                        tracker_interval=50)
+    wal = TrainWAL(wal_cfg)
+    wal.log_state(0, 0, state0)
+    cuts = []
+    checkpoint = wal.maybe_checkpoint
+
+    def checkpoint_and_note(step):
+        taken = checkpoint(step)
+        if taken:
+            cuts.append(wal.db.log.retained_lsn)
+        return taken
+    wal.maybe_checkpoint = checkpoint_and_note
+    n_steps = 15                        # cuts at 4, 8, 12; save at 14
+    final = train_with_recovery(train_step=train_step, init_state=state0,
+                                batch_at=batch_at, n_steps=n_steps, wal=wal)
+    image = wal.crash()
+    assert len(cuts) == 3 and cuts == sorted(cuts) and cuts[0] > 1
+    assert image.log.retained_lsn == image.log.master.bckpt_lsn == cuts[-1]
+    with pytest.raises(TruncatedLogError):
+        image.log.record(cuts[-1] - 1)
+
+    wal2, restored, step, stats = resume_from_crash(
+        image, state0, train_step=train_step, batch_at=batch_at,
+        wal_cfg=wal_cfg, strategy=strategy)
+    assert step == n_steps and stats.scan_from == cuts[-1]
+    for got, want in zip(jax.tree.leaves(restored), jax.tree.leaves(final)):
+        assert got.dtype == want.dtype
+        assert np.asarray(got).tobytes() == np.asarray(want).tobytes()
+
+
+@pytest.mark.parametrize("strategy", [Strategy.LOG0, Strategy.LOG1,
+                                      Strategy.LOG2])
+def test_crash_inside_an_update_save_after_a_discard_recovers_the_last_commit(
+        strategy, monkeypatch):
+    cfg = WALConfig(chunk_elems=CH, tracker_interval=3, cache_pages=4096,
+                    bg_flush_pages=4, ckpt_interval=1)
+    wal = TrainWAL(cfg)
+    committed = _leaves(2, n_list=3)
+    wal.log_state(0, 0, _leaves(0, n_list=3))
+    wal.log_state(1, 1, _leaves(1, n_list=3))
+    assert wal.maybe_checkpoint(1)
+    cut = wal.db.log.retained_lsn
+    assert cut > 1
+    wal.log_state(2, 2, committed)
+    want = dict(tree_to_records(committed, CH))
+    tc = wal.db.tc
+    update = tc.update
+    seen = {"calls": 0}
+
+    def crash_after_seven(txn, table, key, value, skip_unchanged=False):
+        out = update(txn, table, key, value, skip_unchanged)
+        seen["calls"] += 1
+        if seen["calls"] == 7:
+            wal.db.log.flush()
+            wal.db.dc.maybe_background_flush(64)
+            seen["image"] = wal.crash()
+            seen["txn"] = txn
+        return out
+    monkeypatch.setattr(tc, "update", crash_after_seven)
+    wal.log_state(3, 3, _leaves(3, n_list=3))
+    image = seen["image"]
+    assert image.log.retained_lsn == cut
+    stable = [r for r in image.log.scan(cut)
+              if isinstance(r, UpdateRec) and r.txn == seen["txn"]]
+    assert 0 < len(stable) < len(want)
+    _, restored, step, cursor, state_step, stats = TrainWAL.restore(
+        image, jax.eval_shape(lambda: committed), cfg, strategy)
+    assert (step, cursor, state_step) == (2, 2, 2)
+    assert stats.losers == 1 and stats.undone_ops == len(stable)
+    for got, exp in zip(jax.tree.leaves(restored),
+                        jax.tree.leaves(committed)):
+        assert got.dtype == exp.dtype
+        assert np.asarray(got).tobytes() == np.asarray(exp).tobytes()
+
+
+@pytest.mark.parametrize("periods", [2, 6])
+def test_log_after_each_checkpoint_holds_only_the_saves_since(periods):
+    """Whatever the number of periods, each checkpoint leaves only its own
+    records in memory, and before the next one the log holds the records
+    of the saves and heartbeats since: the same count every period."""
+    cfg = WALConfig(chunk_elems=CH, tracker_interval=3, cache_pages=4096,
+                    bg_flush_pages=2, chunk_interval=2, ckpt_interval=4)
+    wal = TrainWAL(cfg)
+    log = wal.db.log
+    wal.log_state(0, 0, _leaves(0, n_list=2))
+    n_chunks = len(list(tree_to_records(_leaves(0, n_list=2), CH)))
+    after_ckpt, before_ckpt = [], []
+
+    def on_step(step, state, metrics):
+        if (step + 1) % cfg.ckpt_interval == 0:
+            after_ckpt.append((log.in_memory_records,
+                               log.end_lsn - log.master.bckpt_lsn + 1,
+                               log.live_image_bytes))
+        elif (step + 2) % cfg.ckpt_interval == 0:
+            before_ckpt.append(log.in_memory_records)
+    train_with_recovery(
+        train_step=lambda st, k: (_leaves(k, n_list=2), {}),
+        init_state=_leaves(0, n_list=2), batch_at=lambda s: s + 1,
+        n_steps=periods * cfg.ckpt_interval, wal=wal, on_step=on_step)
+    assert len(after_ckpt) == periods
+    for held, since_bckpt, image_bytes in after_ckpt:
+        assert held == since_bckpt < n_chunks and image_bytes == 0
+    # the first period's log also held the insert save; from then on one
+    # update save (its chunks, trackers and commit) and two heartbeats
+    first, *rest = before_ckpt
+    assert len(set(rest)) == 1 and n_chunks < rest[0] < first
+    assert log.live_image_bytes == _image_bytes_held(log)
+
+
+def test_live_image_bytes_agree_with_bytes_appended():
+    """The log's count of the image bytes it holds is, summed over
+    appends, what ``log.bytes_appended`` adds; the discard lowers it by
+    what it drops, and a crash image counts only its own records."""
+    cfg = WALConfig(chunk_elems=CH, tracker_interval=3, cache_pages=4096,
+                    ckpt_interval=2)
+    wal = TrainWAL(cfg)
+    log = wal.db.log
+    b0 = obs.value("log.bytes_appended")
+    wal.log_state(0, 0, _leaves(0, n_list=2))
+    wal.log_step_meta(1, 1, 0)
+    wal.log_state(2, 2, _leaves(1, n_list=2))
+    appended = obs.value("log.bytes_appended") - b0
+    assert log.live_image_bytes == appended == _image_bytes_held(log)
+    assert wal.maybe_checkpoint(2)
+    assert log.live_image_bytes == 0 == _image_bytes_held(log)
+    wal.log_state(3, 3, _leaves(2, n_list=2))
+    wal.log_step_meta(4, 4, 3)
+    appended = obs.value("log.bytes_appended") - b0 - appended
+    assert log.live_image_bytes == appended == _image_bytes_held(log)
+    txn = wal.db.tc.begin()             # an unforced tail the crash loses
+    wal.db.tc.update(txn, META_TABLE, b"latest", _META.pack(9, 9, 9))
+    image = wal.crash()
+    assert image.log.live_image_bytes == appended
+    assert image.log.live_image_bytes == _image_bytes_held(image.log)
+    assert log.live_image_bytes == appended + 2 * _META.size

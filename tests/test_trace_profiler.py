@@ -190,14 +190,15 @@ def wal_run(tmp_path_factory):
         ends.append(wal.db.log.end_lsn)
         wal.log_state(1, 1, state1)
         ends.append(wal.db.log.end_lsn)
+        # read before the checkpoint discards the saves' records
+        log_bytes = [_update_bytes(wal.db.log, a, b)
+                     for a, b in zip(ends, ends[1:])]
         wal.log_step_meta(2, 2, 1)
         assert wal.maybe_checkpoint(2)
         image = wal.crash()
         out = TrainWAL.restore(image, jax.eval_shape(lambda: state0), cfg)
     events = list(obs.TRACER.events)
     obs.TRACER.clear()
-    log_bytes = [_update_bytes(wal.db.log, a, b)
-                 for a, b in zip(ends, ends[1:])]
     return dict(events=events, host=host_events(log_dir), wal=wal, out=out,
                 states=(state0, state1), log_bytes=log_bytes, cfg=cfg)
 
@@ -209,7 +210,21 @@ def _roots(wal_run):
 def test_wal_roots_in_order(wal_run):
     assert [r.name for r in _roots(wal_run)] == [
         "wal.log_state", "wal.log_state", "wal.log_step_meta",
-        "wal.restore"]
+        "wal.log.discard", "wal.restore"]
+
+
+def test_wal_checkpoint_discards_the_saves(wal_run):
+    """The checkpoint's discard drops both saves and the heartbeat, each
+    record's before- and after-image; each save reports the image bytes
+    the log holds at its commit."""
+    insert, update, _, discard = _roots(wal_run)[:4]
+    assert insert.attrs["log_live_bytes"] == wal_run["log_bytes"][0]
+    assert update.attrs["log_live_bytes"] == sum(wal_run["log_bytes"])
+    heartbeat = 2 * 24                  # the meta record, before and after
+    assert discard.attrs["bytes"] == sum(wal_run["log_bytes"]) + heartbeat
+    assert discard.attrs["records"] > 2 * n_state_records(
+        wal_run["states"][0], CHUNK)
+    assert wal_run["wal"].db.log.live_image_bytes == 0
 
 
 def test_wal_save_children(wal_run):
@@ -260,7 +275,7 @@ def test_wal_heartbeat_and_pool_flush(wal_run):
 
 
 def test_wal_restore_children(wal_run):
-    restore = _roots(wal_run)[3]
+    restore = _roots(wal_run)[4]
     names = [c.name for c in children(restore)]
     n_leaves = len(jax.tree.leaves(wal_run["states"][0]))
     assert names == (["recover", "wal.restore.scan"]
@@ -313,7 +328,7 @@ def test_wal_spans_on_the_host_plane(wal_run):
     for name, n in [("wal.log_state", 2), ("wal.save.commit", 2),
                     ("wal.log_step_meta", 1), ("pool.bg_flush", 3),
                     ("wal.restore", 1), ("recover", 1), ("checkpoint", 1),
-                    ("wal.restore.scan", 1)]:
+                    ("wal.restore.scan", 1), ("wal.log.discard", 1)]:
         assert names[name] == n, name
     save = [e for e in wal_run["host"] if e[0] == "wal.log_state"][1]
     assert save[3]["chunks_written"] == n_state_records(
