@@ -47,14 +47,34 @@ def decode_chunk(raw: bytes) -> tuple[np.ndarray, str]:
     return arr, dtype
 
 
+def start_host_copies(tree: Any) -> int:
+    """Start the device->host copy of every leaf that has one (a
+    ``jax.Array``), in leaf order, and return how many were started.
+
+    Nothing waits: a later ``np.asarray(leaf)`` waits only for its own
+    copy, so the copies of later leaves land while earlier ones are
+    consumed.  Other leaves (numpy arrays, Python scalars) are left as
+    they are; a leaf whose copy is already under way returns at once."""
+    n = 0
+    for leaf in jax.tree_util.tree_leaves(tree):
+        copy = getattr(leaf, "copy_to_host_async", None)
+        if copy is not None:
+            copy()
+            n += 1
+    return n
+
+
 def tree_to_records(tree: Any, chunk_elems: int = CHUNK_ELEMS
                     ) -> Iterator[tuple[bytes, bytes]]:
     """Yield (key, value) records for every chunk of every leaf.
 
-    Spans per leaf (``TrainWAL.log_state`` saves through here):
-    ``wal.save.pull``, the copy to the host, then ``wal.save.records``,
-    its chunks.  The latter stays open while the caller consumes them, so
-    it times the caller's work on each chunk too."""
+    Every leaf's copy to the host is started before the first record
+    (``start_host_copies``).  Spans per leaf (``TrainWAL.log_state``
+    saves through here): ``wal.save.pull``, the wait for the leaf's copy,
+    then ``wal.save.records``, its chunks.  The latter stays open while
+    the caller consumes them, so it times the caller's work on each chunk
+    too."""
+    start_host_copies(tree)
     for name, leaf in _leaf_paths(tree):
         with TRACER.span("wal.save.pull"):
             arr = np.asarray(leaf)

@@ -38,7 +38,8 @@ from repro.core.dc import make_key
 from repro.obs import metrics as obs_metrics
 from repro.obs.trace import TRACER
 
-from .chunking import records_to_tree, tree_to_records
+from .chunking import (records_to_tree, start_host_copies,
+                       tree_to_records)
 
 META_TABLE = "meta"
 STATE_TABLE = "state"
@@ -83,8 +84,11 @@ class TrainWAL:
         ``wal.log_state`` over ``wal.save.wait``, the per-leaf spans of
         ``tree_to_records``, ``wal.save.commit`` and ``pool.bg_flush``;
         ``wal.log_state`` carries ``log_live_bytes``, the image bytes the
-        log holds in memory at the save's commit."""
+        log holds in memory at the save's commit, and ``leaves_prefetched``,
+        the leaves whose copy to the host was started before the wait, so
+        that each lands as the step that makes it ends."""
         with TRACER.span("wal.log_state", step=step) as sp:
+            n_prefetched = start_host_copies(state)
             with TRACER.span("wal.save.wait"):
                 jax.block_until_ready(state)
             log0 = _C_LOG_BYTES.value
@@ -116,7 +120,8 @@ class TrainWAL:
             sp.set(chunks_written=n_upd, chunks_skipped=n_skip,
                    log_bytes=_C_LOG_BYTES.value - log0,
                    state_bytes=state_bytes,
-                   log_live_bytes=self.db.log.live_image_bytes)
+                   log_live_bytes=self.db.log.live_image_bytes,
+                   leaves_prefetched=n_prefetched)
 
     def log_step_meta(self, step: int, cursor: int, state_step: int) -> None:
         """Per-step heartbeat: step id + data cursor (tiny txn)."""

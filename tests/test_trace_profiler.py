@@ -264,6 +264,33 @@ def test_wal_save_skips_unchanged_chunks():
     assert save.attrs["chunks_skipped"] == n_state_records(state, CHUNK)
 
 
+@pytest.mark.parametrize("on_host", [False, True], ids=["jax", "numpy"])
+def test_wal_save_counts_leaves_prefetched(on_host):
+    state = _state(1)
+    if on_host:
+        state = jax.tree.map(np.asarray, state)
+    wal = TrainWAL(WALConfig(chunk_elems=CHUNK, cache_pages=256))
+    obs.enable()
+    wal.log_state(0, 0, state)
+    save, = [r for r in obs.build_tree(obs.TRACER.events) if r.name != "gc"]
+    assert save.attrs["leaves_prefetched"] == (
+        0 if on_host else len(jax.tree.leaves(state)))
+
+
+def test_every_copy_to_host_starts_before_the_first_record(monkeypatch):
+    array_cls = type(jnp.zeros(()))
+    started = []
+    copy = array_cls.copy_to_host_async
+
+    def record(self):
+        started.append(id(self))
+        return copy(self)
+    monkeypatch.setattr(array_cls, "copy_to_host_async", record)
+    state = _state(1)
+    next(tree_to_records(state, CHUNK))
+    assert started == [id(leaf) for leaf in jax.tree.leaves(state)]
+
+
 def test_wal_heartbeat_and_pool_flush(wal_run):
     beat = _roots(wal_run)[2]
     assert beat.attrs["step"] == 2
