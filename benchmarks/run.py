@@ -89,10 +89,9 @@ def write_summary(results: list[dict], failures: list[str],
 def main() -> None:
     fast = "--full" not in sys.argv
     from . import (appendix_d_variants, archive_bench, faults_bench,
-                   fig2_cache_sweep, fig3_ckpt_interval, kernel_bench,
-                   media_bench, pagepack_bench, parallel_apply_bench,
-                   recovery_bench, replication_bench, roofline_table,
-                   trainstore_bench)
+                   fig2_cache_sweep, fig3_ckpt_interval, media_bench,
+                   pagepack_bench, parallel_apply_bench, recovery_bench,
+                   replication_bench, trainstore_bench)
     from repro.obs.export import Sampler, prometheus_text
     ART.mkdir(parents=True, exist_ok=True)
     failures: list[str] = []
@@ -105,8 +104,7 @@ def main() -> None:
     for mod in (fig2_cache_sweep, fig3_ckpt_interval, appendix_d_variants,
                 recovery_bench, pagepack_bench, replication_bench,
                 parallel_apply_bench, archive_bench, media_bench,
-                faults_bench, trainstore_bench, kernel_bench,
-                roofline_table):
+                faults_bench, trainstore_bench):
         try:
             out = mod.run(fast=fast)
         except Exception:

@@ -1,3 +1,2 @@
-from .base import (DECODE_32K, LONG_500K, PREFILL_32K, SHAPES, TRAIN_4K,
-                   ModelConfig, ShapeConfig, shape_applicable)
-from .registry import all_cells, get_config, list_archs
+from .base import DECODE_32K, TRAIN_4K, ModelConfig, ShapeConfig
+from .registry import get_config, list_archs

@@ -8,7 +8,6 @@ from __future__ import annotations
 
 import dataclasses
 from dataclasses import dataclass, field
-from typing import Optional, Tuple
 
 
 @dataclass(frozen=True)
@@ -67,11 +66,6 @@ class ModelConfig:
     @property
     def attention_free(self) -> bool:
         return self.family == "ssm"
-
-    @property
-    def subquadratic(self) -> bool:
-        """Can this arch serve 500k-token contexts? (SSM / hybrid only)"""
-        return self.family in ("ssm", "hybrid")
 
     @property
     def is_enc_dec(self) -> bool:
@@ -175,15 +169,4 @@ class ShapeConfig:
 
 
 TRAIN_4K = ShapeConfig("train_4k", 4_096, 256, "train")
-PREFILL_32K = ShapeConfig("prefill_32k", 32_768, 32, "prefill")
 DECODE_32K = ShapeConfig("decode_32k", 32_768, 128, "decode")
-LONG_500K = ShapeConfig("long_500k", 524_288, 1, "decode")
-
-SHAPES: Tuple[ShapeConfig, ...] = (TRAIN_4K, PREFILL_32K, DECODE_32K, LONG_500K)
-
-
-def shape_applicable(cfg: ModelConfig, shape: ShapeConfig) -> tuple[bool, str]:
-    """Whether (arch, shape) is a runnable cell; reason when skipped."""
-    if shape.name == "long_500k" and not cfg.subquadratic:
-        return False, "full-attention arch: 500k context is O(L^2)-infeasible (skip per DESIGN.md)"
-    return True, ""

@@ -5,7 +5,7 @@ with the exact public-literature geometry; this registry maps ids to configs.
 """
 from __future__ import annotations
 
-from .base import SHAPES, ModelConfig, ShapeConfig, shape_applicable
+from .base import ModelConfig
 
 
 def _load() -> dict[str, ModelConfig]:
@@ -35,14 +35,3 @@ def list_archs() -> list[str]:
     if _REGISTRY is None:
         _REGISTRY = _load()
     return sorted(_REGISTRY)
-
-
-def all_cells() -> list[tuple[ModelConfig, ShapeConfig, bool, str]]:
-    """Every (arch x shape) cell with applicability flag + skip reason."""
-    out = []
-    for name in list_archs():
-        cfg = get_config(name)
-        for shape in SHAPES:
-            ok, why = shape_applicable(cfg, shape)
-            out.append((cfg, shape, ok, why))
-    return out

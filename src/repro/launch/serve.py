@@ -3,9 +3,8 @@
     PYTHONPATH=src python -m repro.launch.serve --arch qwen2.5-3b \
         --preset smoke --batch 4 --prompt-len 32 --gen 16
 
-Production lowering of the same decode step (one token against a seq_len KV
-cache on the 16x16 / 2x16x16 mesh) is exercised by launch.dryrun; this driver
-runs the identical code path at CPU scale.
+Each decode step is one token against a seq_len KV cache, run here at CPU
+scale.
 """
 from __future__ import annotations
 

@@ -1,6 +1,6 @@
 """Step builders: the jit-able train / prefill / decode entry points with
-their input ShapeDtypeStruct specs + shardings — shared by the dry-run, the
-roofline analysis, and the real train/serve drivers.
+their input ShapeDtypeStruct specs + shardings, for lowering and compiling a
+step on a mesh without allocating it.
 """
 from __future__ import annotations
 
@@ -65,9 +65,8 @@ def make_train_spec(cfg: ModelConfig, shape: ShapeConfig, mesh: Mesh,
         # grads adopt the optimizer-state sharding.  Intended to lower the
         # data-parallel reduction to reduce-scatter (1x traffic) instead of
         # all-reduce (2x); measured NO-OP on this XLA version — the
-        # partitioner emits AR+slice anyway (EXPERIMENTS.md §Perf, llama
-        # it3, refuted).  Kept because it is semantically correct and
-        # future partitioners (Shardy) fuse it.
+        # partitioner emits AR+slice anyway.  Kept because it is
+        # semantically correct and future partitioners (Shardy) fuse it.
         grads = jax.tree.map(
             lambda g, s: jax.lax.with_sharding_constraint(g, s),
             grads, p_sh)

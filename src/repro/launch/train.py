@@ -4,8 +4,8 @@
         --preset 30m --steps 60 --crash-at 35
 
 Presets scale the assigned architecture's family to CPU-runnable sizes
-(--preset full uses the assigned geometry; that is what the dry-run lowers on
-the production mesh).  The loop is wired to the logical-recovery state store:
+(--preset full uses the assigned geometry).  The loop is wired to the
+logical-recovery state store:
 per-step heartbeats, incremental chunk transactions, RSSP checkpoints; with
 --crash-at it hard-crashes mid-run and then restores + replays, verifying the
 resumed state matches exactly.

@@ -191,9 +191,7 @@ def _sdpa_chunked(q: Array, k: Array, v: Array, causal: bool) -> Array:
 
     Scans q in blocks of Q_CHUNK; for each, scans kv in blocks of KV_CHUNK
     carrying (running max, running denom, weighted accumulator).  Peak temp
-    is one (B,KV,G,Cq,Ckv) tile instead of the full S^2 score matrix — this
-    is the same tiling the Pallas kernel (kernels/flash_attention.py) uses
-    natively in VMEM.
+    is one (B,KV,G,Cq,Ckv) tile instead of the full S^2 score matrix.
     """
     B, Sq, H, hd = q.shape
     Skv, KV = k.shape[1], k.shape[2]

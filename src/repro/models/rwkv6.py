@@ -7,12 +7,9 @@ Time-mix (WKV6) recurrence per head (state S: key-dim x value-dim):
     S_t = diag(w_t) S_{t-1} + k_t v_t^T,   w_t = exp(-exp(wr_t))  (data-dep.)
 
 The model forward uses an exact ``lax.scan`` over time (compile-time is
-T-independent; the production TPU path is the chunked Pallas kernel in
-kernels/wkv6.py, which computes intra-chunk interactions in log-space inside
-VMEM).  Decode carries (S, token-shift) state — O(1) per token, which is why
-rwkv6 runs the ``long_500k`` cell.
+T-independent).  Decode carries (S, token-shift) state — O(1) per token.
 
-Deviations noted in DESIGN.md: token-shift lerp coefficients are static (the
+Deviations from the paper: token-shift lerp coefficients are static (the
 paper's LoRA-produced dynamic lerp is an accuracy refinement orthogonal to the
 systems work); decay LoRA is kept because it is the data-dependence itself.
 """

@@ -5,14 +5,13 @@ Mamba2 mixer per layer (multi-head SSD, n_groups=1):
     h_t = exp(A dt_t) h_{t-1} + dt_t * x_t (x) B_t        h: (H, P, N)
     y_t = h_t . C_t + D x_t
 with a causal depthwise conv (width 4) on (x, B, C) and a gated RMS-norm
-before out-projection.  The model forward is an exact ``lax.scan`` over time
-(chunked production path: kernels/ssd_scan.py).
+before out-projection.  The model forward is an exact ``lax.scan`` over time.
 
 The shared attention block's weights are reused at every invocation; each
 invocation keeps its *own* KV cache (stacked on a leading invocation axis) —
 weight sharing is a parameter-count device, not a cache-sharing one.
-`long_500k` runs: mamba state decode is O(1), and the shared-attn KV cache's
-sequence axis is shardable over the data mesh axis (SP).
+Mamba state decode is O(1), and the shared-attn KV cache's sequence axis is
+shardable over the data mesh axis (SP).
 """
 from __future__ import annotations
 

@@ -1,7 +1,7 @@
-"""Sharding rules: DP / FSDP / TP / EP / SP over the production mesh.
+"""Sharding rules: DP / FSDP / TP / EP / SP over a device mesh.
 
-Mesh axes: ``(data, model)`` single-pod (16x16), ``(pod, data, model)``
-multi-pod (2x16x16).  Recipe (MaxText-style FSDP+TP):
+Mesh axes: ``(data, model)``, or ``(pod, data, model)`` across pods.
+Recipe (MaxText-style FSDP+TP):
 
   * batch        -> ('pod','data')           data parallel (hierarchical)
   * TP           -> 'model' on head/ff/vocab dims (Megatron split)
@@ -12,7 +12,7 @@ multi-pod (2x16x16).  Recipe (MaxText-style FSDP+TP):
                     'model' when kv-heads don't divide) — flash-decode style
                     partial-softmax combine is generated by SPMD
   * optimizer    -> same sharding as its param (ZeRO-1-equivalent: fp32
-                    master+m+v bytes / 256 devices)
+                    master+m+v bytes / devices)
 
 Every rule degrades to replication when a dim doesn't divide the axis.
 """
@@ -25,9 +25,9 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.configs.base import ModelConfig, ShapeConfig
+from repro.configs.base import ModelConfig
 
-# Layout selector (beyond-paper perf iteration, EXPERIMENTS.md §Perf):
+# Layout selector:
 #   'tp'   — Megatron TP over 'model' + FSDP over 'data'  (baseline)
 #   'fsdp' — batch + weight sharding over BOTH axes, no tensor parallelism
 #            (except MoE experts, which stay EP over 'model').  Right for
@@ -252,24 +252,3 @@ def to_named(tree_pspec: Any, mesh: Mesh) -> Any:
     return jax.tree.map(
         lambda p: NamedSharding(mesh, p), tree_pspec,
         is_leaf=lambda x: isinstance(x, P))
-
-
-def recommended_layout(cfg: ModelConfig, shape: Optional[ShapeConfig] = None
-                       ) -> str:
-    """Best measured layout per family (EXPERIMENTS.md §Perf widened sweep).
-
-    * MoE            -> 'ep'   (model axis reserved for experts)
-    * sub-1B models  -> 'dp'   (replication beats gathering)
-    * zamba2 prefill -> 'tp'   (fsdp regresses: B/C/dt conv states gather)
-    * dense/ssm      -> 'fsdp' (no TP: 4-40x lower collective traffic)
-    decode shapes keep 'tp' (KV-cache partitioning relies on the model axis).
-    """
-    if shape is not None and shape.kind == "decode":
-        return "tp"
-    if cfg.family == "moe":
-        return "ep"
-    if cfg.n_params() < 1_000_000_000:
-        return "dp"
-    if cfg.family == "hybrid" and shape is not None and shape.kind == "prefill":
-        return "tp"
-    return "fsdp"

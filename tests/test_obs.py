@@ -410,13 +410,13 @@ def test_bench_diff_flags_guarded_regressions_only():
     old = _summary("fast", [
         ("recovery_pipeline", "recovery_redo/Log1", 100.0),
         ("recovery_pipeline", "recovery_redo/Log0", 100.0),
-        ("kernel_bench", "kernel/sort", 100.0),      # not oracle-guarded
+        ("fig2_cache_sweep", "fig2/cache", 100.0),   # not oracle-guarded
         ("media", "media/tiny", 10.0),               # below the noise floor
     ])
     new = _summary("fast", [
         ("recovery_pipeline", "recovery_redo/Log1", 250.0),   # 2.5x: flag
         ("recovery_pipeline", "recovery_redo/Log0", 150.0),   # 1.5x: ok
-        ("kernel_bench", "kernel/sort", 900.0),               # unguarded
+        ("fig2_cache_sweep", "fig2/cache", 900.0),             # unguarded
         ("media", "media/tiny", 45.0),                        # noise floor
     ])
     regressions = bench_diff.compare_runs(old, new)

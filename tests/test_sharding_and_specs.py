@@ -1,7 +1,7 @@
 """Distribution layer: sharding rules + lowering specs on a small host mesh.
 
 Runs in a subprocess with 8 forced host devices so the main test process
-keeps its single-device view (dryrun.py's 512-device trick, miniaturized).
+keeps its single-device view.
 """
 import json
 import os
